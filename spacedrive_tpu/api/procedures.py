@@ -1401,14 +1401,16 @@ def _search(r: Router) -> None:
     def search_duplicates(node, library, input):
         from ..objects.dedup import exact_duplicate_groups
         return exact_duplicate_groups(
-            library, location_id=(input or {}).get("location_id"))
+            library, location_id=(input or {}).get("location_id"),
+            limit=int((input or {}).get("limit", 1000)))
 
     @r.query("search.nearDuplicates", library=True)
     def search_near_duplicates(node, library, input):
         from ..objects.dedup import near_duplicates
         return near_duplicates(
             library,
-            max_distance=int((input or {}).get("max_distance", 10)))
+            max_distance=int((input or {}).get("max_distance", 10)),
+            limit=int((input or {}).get("limit", 1000)))
 
 
 # -- sync. (api/sync.rs) ---------------------------------------------------

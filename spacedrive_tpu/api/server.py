@@ -26,7 +26,7 @@ import mimetypes
 import os
 from typing import Any, Dict, Optional
 
-from aiohttp import WSMsgType, web
+from aiohttp import WSCloseCode, WSMsgType, web
 
 from .. import channels, chaos, tasks, telemetry, threadctx, tracing
 from ..locations.paths import IsolatedPath
@@ -142,6 +142,9 @@ class ApiServer:
         # edge at bench scale.
         self._inflight = channels.channel(
             "api.http.inflight", capacity_cap=http_inflight_cap)
+        # Live /rspc sockets (loop thread only), closed at shutdown.
+        self._websockets: set = set()
+        self.app.on_shutdown.append(self._close_websockets)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -157,6 +160,15 @@ class ApiServer:
         if self._runner is not None:
             await self._runner.cleanup()
             self._runner = None
+
+    async def _close_websockets(self, _app: web.Application) -> None:
+        """Shutdown hook: aiohttp's cleanup waits out every open handler
+        (its 60 s shutdown_timeout), so one connected client held stop()
+        for minutes. Closing the live sockets first lets each handler's
+        teardown run at once."""
+        for ws in list(self._websockets):
+            await with_timeout("api.ws.send", ws.close(
+                code=WSCloseCode.GOING_AWAY, message=b"server shutdown"))
 
     # -- handlers ----------------------------------------------------------
 
@@ -286,6 +298,7 @@ class ApiServer:
     async def _rspc_ws(self, request: web.Request) -> web.WebSocketResponse:
         ws = web.WebSocketResponse()
         await with_timeout("api.ws.prepare", ws.prepare(request))
+        self._websockets.add(ws)
         subscriptions: Dict[Any, Any] = {}
         loop = asyncio.get_running_loop()
 
@@ -409,6 +422,7 @@ class ApiServer:
                 elif msg.type == WSMsgType.ERROR:
                     break
         finally:
+            self._websockets.discard(ws)
             # Cancellation-safe teardown: every unsub runs before the
             # first await (an await inside the loop would die on the
             # first CancelledError and strand later subscriptions'

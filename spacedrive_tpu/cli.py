@@ -115,6 +115,9 @@ def _cmd_serve(args) -> int:
     import asyncio
 
     from .api.server import serve
+    from .xla_env import enable_compile_cache
+
+    enable_compile_cache()
 
     try:
         asyncio.run(serve(args.data_dir, args.host, args.port))

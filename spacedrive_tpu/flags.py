@@ -198,7 +198,7 @@ declare(
     "SDTPU_H2D_GBPS", None, parse_float,
     "Pin the host→device link-rate probe to a fixed GB/s "
     "(ops/staging.py) instead of measuring — benchmark pinning and "
-    "thin-tunnel hosts.")
+    "slow-link hosts.")
 
 declare(
     "SDTPU_DONATE_BUFFERS", True, parse_onoff,

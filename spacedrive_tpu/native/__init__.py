@@ -58,11 +58,37 @@ def _native_dir() -> str:
             os.path.abspath(__file__)))), "native")
 
 
+def _host_key() -> str:
+    """Build-dir key for this host's CPU. The .so is built with
+    -march=native, so a binary built on another CPU (a copied tree, a
+    shared disk) can die here with SIGILL instead of raising; keying
+    the build dir by the CPU's feature flags makes such a host build
+    its own from native/sdio.cpp."""
+    import hashlib
+    import platform
+
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line
+                    break
+    except OSError:
+        pass
+    return hashlib.sha1(
+        f"{platform.machine()} {flags}".encode()).hexdigest()[:12]
+
+
+def _build_subdir() -> str:
+    return os.path.join("build", _host_key())
+
+
 def _lib_path() -> str:
     env = os.environ.get("SD_NATIVE_LIB")
     if env:
         return env
-    return os.path.join(_native_dir(), "build", "libsdio.so")
+    return os.path.join(_native_dir(), _build_subdir(), "libsdio.so")
 
 
 def _declare(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -122,7 +148,8 @@ def _load() -> Optional[ctypes.CDLL]:
             # warm this up at bootstrap (Node.__init__).
             try:
                 subprocess.run(
-                    ["make", "-C", _native_dir()], check=True,
+                    ["make", "-C", _native_dir(),
+                     f"BUILD={_build_subdir()}"], check=True,
                     capture_output=True, timeout=120)
             except Exception:
                 if not os.path.exists(path):

@@ -502,6 +502,10 @@ IDENT_DEVICE_FALLBACK = counter(
     "sd_identifier_device_fallback_total",
     "auto-backend batches that downgraded jax->host (link probe said "
     "the H2D link loses to the native plane)")
+IDENT_PREFETCH_FAILURES = counter(
+    "sd_identifier_prefetch_failures_total",
+    "Hash-ahead worker chunks that raised; the consumer re-fetches and "
+    "re-hashes the chunk inline (the traceback is logged)")
 IDENT_READ_ERRORS = counter(
     "sd_identifier_read_errors_total",
     "Files dropped from CAS batches by read errors")
@@ -569,6 +573,11 @@ STAGE_FALLBACK_FILES = counter(
     "Files that degraded PER-FILE from the native packed reader to "
     "the Python reader (bad row status: vanished, permission, short "
     "read, chaos-injected EIO) inside an otherwise-native batch")
+STAGE_H2D_PROBE_FAILURES = counter(
+    "sd_stage_h2d_probe_failures_total",
+    "Host->device link probes that raised (ops/staging.h2d_gbps): the "
+    "probe then reports 0 GB/s and auto routing stays on the host "
+    "plane, so a nonzero count means the device was not used")
 STAGE_POOL_BUFFERS = gauge(
     "sd_stage_pool_buffers",
     "Pooled staging pages currently checked out to in-flight batches "

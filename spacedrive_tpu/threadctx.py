@@ -223,8 +223,11 @@ def _locks_now(obj: Any, c: AttrContract) -> frozenset:
 
 def _note_write(obj: Any, cls_name: str, attr: str,
                 c: AttrContract) -> None:
-    if getattr(_tls, "busy", False):
-        return  # the recorder's own metrics must not re-enter it
+    if getattr(_tls, "busy", False) or obj is RACE_TRACKED_WRITES:
+        # The recorder's own metrics must not re-enter it: a registry
+        # reset zeroes RACE_TRACKED_WRITES while holding its lock, and
+        # counting that write would take the same lock again.
+        return
     _tls.busy = True
     try:
         RACE_TRACKED_WRITES.inc()

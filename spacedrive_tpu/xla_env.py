@@ -1,10 +1,10 @@
-"""XLA environment bootstrap helpers (no jax import — must be callable
-before the first ``import jax`` takes effect).
+"""XLA environment bootstrap helpers.
 
 The virtual host-device count used for CPU-mesh testing and the driver's
 multichip dry-run is carried in ``XLA_FLAGS`` and is only read once, when
 the CPU backend initializes; these helpers centralize the mutation so the
-test conftest and ``__graft_entry__`` can't drift apart.
+test conftest and ``__graft_entry__`` can't drift apart. Importing this
+module imports no jax.
 """
 
 from __future__ import annotations
@@ -13,6 +13,13 @@ import os
 import re
 
 _FLAG = "xla_force_host_platform_device_count"
+
+# The persistent compile cache's default home: fixed inside the checkout
+# (gitignored), because the path is part of the cache key — a directory
+# that moves between runs never hits.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
 def ensure_host_device_count(n: int) -> None:
@@ -31,3 +38,22 @@ def ensure_host_device_count(n: int) -> None:
     else:
         return
     os.environ["XLA_FLAGS"] = flags
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first
+    compile; returns the directory in use.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` from the environment is used as is
+    (JAX reads it itself) and nothing else is set. Otherwise the cache
+    lives at the fixed ``DEFAULT_COMPILE_CACHE``. Entry points call
+    this (chip_smoke.py, bench.py, ``serve``, tools/perf_smoke.py);
+    importing the package does not, so the tests stay uncached.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE

@@ -2,11 +2,12 @@
 round.
 
 The repo records one `BENCH_rNN.json` per growth round, but the
-artifact shape evolved with the harnesses: r01–r05 are driver-wrapped
-kernel benches (`{n, cmd, rc, tail, parsed}`), r06 wraps an
-overlap_bench sweep, r07 wraps a fleet-observatory snapshot, r08/r09
-are raw load_bench artifacts, r10 is a raw overlap_bench artifact.
-Reading the trajectory therefore meant opening ten files with four
+artifact shape evolved with the harnesses: driver-wrapped rounds
+(`{n, cmd, rc, tail, parsed}`) carry bench.py's kernel line or an
+overlap_bench sweep (r06), r07 wraps a fleet-observatory snapshot,
+r08/r09 are raw load_bench artifacts, r10 is a raw overlap_bench
+artifact (r01–r05, remote-chip kernel rounds, were removed in PR 21).
+Reading the trajectory therefore meant opening many files with several
 schemas. This tool normalizes every round into one row — headline
 metric, unit, and the round's own gate/validity verdict — validates
 each against its shape (exit 1 on any schema problem: the committed

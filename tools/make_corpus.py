@@ -27,9 +27,15 @@ import random
 
 def make_corpus(out_dir: str, files: int = 1000, dup_rate: float = 0.1,
                 images: int = 0, seed: int = 0, depth: int = 3,
-                small_only: bool = False) -> dict:
+                small_only: bool = False, max_size: int = 0) -> dict:
     """small_only caps files at 8 KiB — the 100k/1M-scale configs, where
-    generating the default multi-MiB tail would dominate the run."""
+    generating the default multi-MiB tail would dominate the run.
+    max_size > 0 clamps every sampled size to at most that many bytes
+    (the mix keeps its shape below the cap).
+
+    The returned stats carry `planted`: (duplicate, source) path pairs
+    relative to out_dir, one per planted exact duplicate — the ground
+    truth for duplicate groups. corpus.json keeps the counts only."""
     rng = random.Random(seed)
     os.makedirs(out_dir, exist_ok=True)
     dirs = [out_dir]
@@ -40,9 +46,10 @@ def make_corpus(out_dir: str, files: int = 1000, dup_rate: float = 0.1,
             dirs.append(p)
 
     stats = {"files": 0, "bytes": 0, "duplicates": 0, "images": 0}
+    planted = []  # (duplicate, source), relative to out_dir
     blobs = []  # (payload reference) for duplicate sampling
 
-    def size_sample() -> int:
+    def mixed_size() -> int:
         if small_only:
             return rng.randrange(256, 8 * 1024)
         r = rng.random()
@@ -52,6 +59,10 @@ def make_corpus(out_dir: str, files: int = 1000, dup_rate: float = 0.1,
             return rng.randrange(100 * 1024 + 1, 1 << 20)  # sampled CAS
         return rng.randrange(1 << 20, 8 << 20)             # multi-MiB
 
+    def size_sample() -> int:
+        n = mixed_size()
+        return min(n, max_size) if max_size > 0 else n
+
     for i in range(files):
         path = os.path.join(rng.choice(dirs), f"f{i:06d}.bin")
         if blobs and rng.random() < dup_rate:
@@ -59,6 +70,8 @@ def make_corpus(out_dir: str, files: int = 1000, dup_rate: float = 0.1,
             with open(src, "rb") as f:
                 payload = f.read()
             stats["duplicates"] += 1
+            planted.append((os.path.relpath(path, out_dir),
+                            os.path.relpath(src, out_dir)))
         else:
             payload = rng.randbytes(size_sample())
         with open(path, "wb") as f:
@@ -99,7 +112,7 @@ def make_corpus(out_dir: str, files: int = 1000, dup_rate: float = 0.1,
 
     with open(os.path.join(out_dir, "corpus.json"), "w") as f:
         json.dump({"seed": seed, **stats}, f)
-    return stats
+    return {**stats, "planted": planted}
 
 
 if __name__ == "__main__":
@@ -111,6 +124,7 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--small", action="store_true")
     args = ap.parse_args()
-    print(json.dumps(make_corpus(args.out_dir, args.files, args.dup_rate,
-                                 args.images, args.seed,
-                                 small_only=args.small)))
+    stats = make_corpus(args.out_dir, args.files, args.dup_rate,
+                        args.images, args.seed, small_only=args.small)
+    stats.pop("planted")
+    print(json.dumps(stats))
