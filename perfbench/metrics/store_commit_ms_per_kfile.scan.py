@@ -1,0 +1,13 @@
+"""Milliseconds per 1,000 files of the window's scan rounds spent in write-
+transaction COMMIT seconds of the store (sd_store_commit_seconds, a
+program counter); the window delta."""
+
+
+def read(run):
+    files = sum(r["files"] for r in run.window_rounds()
+                if r["kind"] == "scan")
+    delta = run.counter_delta(
+        "sd_store_commit_seconds", None, "sum")
+    if not files or delta is None:
+        return None
+    return delta * 1e3 / (files / 1e3)
