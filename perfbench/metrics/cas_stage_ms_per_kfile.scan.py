@@ -1,0 +1,15 @@
+"""Milliseconds per 1,000 files of the window's scan rounds spent staging
+the files into payload rows (native or Python reads), in the device CAS
+path (sd_cas_phase_seconds_total{phase="stage"}, a program counter
+taken in ops/staging.py and ops/blake3_jax.py); the window delta. None
+where the program has no such counter."""
+
+
+def read(run):
+    files = sum(r["files"] for r in run.window_rounds()
+                if r["kind"] == "scan")
+    delta = run.counter_delta(
+        "sd_cas_phase_seconds_total", {"phase": "stage"}, "value")
+    if not files or delta is None:
+        return None
+    return delta * 1e3 / (files / 1e3)

@@ -210,6 +210,35 @@ class TelemetryReporter:
             self._task = None
 
 
+class LoopLagProbe:
+    """Event-loop lag: one task that sleeps a fixed INTERVAL_S and
+    observes how late it wakes into sd_event_loop_lag_seconds. Lateness
+    is time the loop spent in other callbacks past the due time — an
+    rspc handler running on the loop (search.paths) shows here before
+    any client sees its tail. Ten wake-ups a second; always on."""
+
+    INTERVAL_S = 0.1
+
+    def __init__(self, owner: str = "loop-lag"):
+        self._owner = owner
+        self._task: Optional[asyncio.Task] = None
+
+    def start(self) -> None:
+        async def loop():
+            clock = asyncio.get_running_loop().time
+            while True:
+                due = clock() + self.INTERVAL_S
+                await asyncio.sleep(self.INTERVAL_S)
+                telemetry.EVENT_LOOP_LAG.observe(max(0.0, clock() - due))
+        if self._task is None:
+            self._task = tasks.spawn("loop-lag", loop(), owner=self._owner)
+
+    def stop(self) -> None:
+        if self._task is not None:
+            self._task.cancel()
+            self._task = None
+
+
 class Node:
     def __init__(self, data_dir: str):
         # Production nodes honor SDTPU_SANITIZE=1 too: violations count
@@ -251,6 +280,7 @@ class Node:
         # subsystem) fleet view; serves fleet.health / fleet.metrics /
         # fleet.trace.export.
         self.fleet = FleetMonitor(self, owner=f"{self.task_owner}/fleet")
+        self.loop_lag = LoopLagProbe(owner=f"{self.task_owner}/loop-lag")
         # Incident observatory (incidents.py): the always-on black
         # box. install() is process-global and idempotent (first node
         # wins, like the sanitizer); recovery of a prior crash's
@@ -285,6 +315,7 @@ class Node:
             self.telemetry_reporter.start()
             self.health.start()
             self.fleet.start()
+            self.loop_lag.start()
         except RuntimeError:
             pass  # no running loop (sync tests); node.metrics and the
             # on-demand node.health / fleet.health samples still work
@@ -352,6 +383,7 @@ class Node:
         self.telemetry_reporter.stop()
         self.health.stop()
         self.fleet.stop()
+        self.loop_lag.stop()
         await self.thumbnailer.stop()
         if self.p2p is not None:
             await self.p2p.stop()

@@ -15,11 +15,14 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
+from .. import tracing
 from ..jobs.job import EarlyFinish, JobContext, StatefulJob, StepOutcome, register_job
 from ..locations.paths import IsolatedPath
 from ..ops.cas import file_checksum
+from ..telemetry import VAL_BATCHED_READ, VAL_PAGE_COMPARE, VAL_PAGE_ROWS
 
 CHUNK_SIZE = 10
 
@@ -147,16 +150,17 @@ class ObjectValidatorJob(StatefulJob):
         from ..parallel.mesh import batch_mesh
 
         small, big = [], []
-        for r, path in jobs:
-            try:
-                sz = _os.path.getsize(path)
-            except OSError as e:
-                errors.append(f"{path}: {e}")
-                continue
-            (small if sz <= self.SMALL_FILE_CAP else big).append(
-                (r, path, sz))
+        with tracing.phase("validator.phase/rows", VAL_PAGE_ROWS):
+            for r, path in jobs:
+                try:
+                    sz = _os.path.getsize(path)
+                except OSError as e:
+                    errors.append(f"{path}: {e}")
+                    continue
+                (small if sz <= self.SMALL_FILE_CAP else big).append(
+                    (r, path, sz))
 
-        small.sort(key=lambda t: t[2])
+            small.sort(key=lambda t: t[2])
 
         def _padded_row(sz: int) -> int:
             # the dispatch grid pads every row to the batch's pow2 max
@@ -168,23 +172,26 @@ class ObjectValidatorJob(StatefulJob):
         i = 0
         while i < len(small):
             batch, blobs = [], []
-            while i < len(small) and len(batch) < self.BATCH_ROWS:
-                r, path, sz = small[i]
-                # Budget the PADDED grid, not the raw payload: one 4 MiB
-                # file after 500 tiny ones would otherwise balloon the
-                # dispatch to rows × pow2(max) ≈ GiBs of zeros.
-                if batch and (len(batch) + 1) * _padded_row(sz) \
-                        > self.batch_bytes:
-                    break
-                i += 1
-                try:
-                    with open(path, "rb") as f:
-                        data = f.read()
-                except OSError as e:
-                    errors.append(f"{path}: {e}")
-                    continue
-                blobs.append(data)
-                batch.append((r, path))
+            # One read phase a page, not a clock pair per file.
+            with tracing.phase("validator.phase/read", VAL_BATCHED_READ):
+                while i < len(small) and len(batch) < self.BATCH_ROWS:
+                    r, path, sz = small[i]
+                    # Budget the PADDED grid, not the raw payload: one
+                    # 4 MiB file after 500 tiny ones would otherwise
+                    # balloon the dispatch to rows × pow2(max) ≈ GiBs of
+                    # zeros.
+                    if batch and (len(batch) + 1) * _padded_row(sz) \
+                            > self.batch_bytes:
+                        break
+                    i += 1
+                    try:
+                        with open(path, "rb") as f:
+                            data = f.read()
+                    except OSError as e:
+                        errors.append(f"{path}: {e}")
+                        continue
+                    blobs.append(data)
+                    batch.append((r, path))
             if blobs:
                 # Guarded dispatch (round 10): the page's only
                 # sanctioned fetch is checksums_words_batched's
@@ -222,25 +229,29 @@ class ObjectValidatorJob(StatefulJob):
     def _step(self, ctx: JobContext, data, step) -> StepOutcome:
         db, sync = ctx.db, ctx.library.sync
         loc_path = data["location_path"]
-        rows = self._fetch_rows(db, data)
+        # The device path's page-level phases (sd_validator_* path=page).
+        device = self.backend == "jax"
+        with (tracing.phase("validator.phase/rows", VAL_PAGE_ROWS)
+              if device else nullcontext()):
+            rows = self._fetch_rows(db, data)
+            jobs: List[Tuple[dict, str]] = []
+            for r in rows:
+                iso = IsolatedPath.from_db_row(
+                    self.location_id, False, r["materialized_path"],
+                    r["name"], r["extension"])
+                jobs.append((r, iso.join_on(loc_path)))
         if not rows:
             return StepOutcome()
         # Advance past this page only once it is fully processed (end of
         # this method) — an interrupted step replays the same page, and
         # the guarded UPDATE keeps the replay idempotent.
         next_cursor = rows[-1]["id"] + 1
-        jobs: List[Tuple[dict, str]] = []
-        for r in rows:
-            iso = IsolatedPath.from_db_row(
-                self.location_id, False, r["materialized_path"],
-                r["name"], r["extension"])
-            jobs.append((r, iso.join_on(loc_path)))
 
         errors: List[str] = []
         results: List[Tuple[dict, str, str]] = []  # (row, path, checksum)
 
         from .. import native
-        if self.backend == "jax" and jobs:
+        if device and jobs:
             # Device plane: each file's chunk chain is sequence-sharded
             # across the mesh and streamed in windows (ops/seqhash.py
             # StreamingShardedChecksum) — bounded memory at any file
@@ -286,16 +297,18 @@ class ObjectValidatorJob(StatefulJob):
             # loop-affine, so violations ride the outcome metadata and
             # execute_step emits them after the hop back to the loop.
             integrity_events = []
-            for r, path, checksum in results:
-                if checksum != r.get("expected"):
-                    data["mismatched"] += 1
-                    errors.append(
-                        f"CHECKSUM MISMATCH {path}: stored "
-                        f"{r.get('expected')}, current {checksum}")
-                    integrity_events.append({
-                        "type": "IntegrityViolation",
-                        "file_path_id": r["id"], "path": path,
-                    })
+            with (tracing.phase("validator.phase/compare", VAL_PAGE_COMPARE)
+                  if device else nullcontext()):
+                for r, path, checksum in results:
+                    if checksum != r.get("expected"):
+                        data["mismatched"] += 1
+                        errors.append(
+                            f"CHECKSUM MISMATCH {path}: stored "
+                            f"{r.get('expected')}, current {checksum}")
+                        integrity_events.append({
+                            "type": "IntegrityViolation",
+                            "file_path_id": r["id"], "path": path,
+                        })
             data["validated"] += len(results)
             data["cursor"] = next_cursor
             ctx.progress(message=(

@@ -23,7 +23,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import cas, jit_registry
-from .. import flags
+from .. import flags, tracing
+from ..telemetry import (
+    CAS_DEVICE_BYTES,
+    CAS_MESSAGE_BYTES,
+    CAS_PHASE_DISPATCH,
+    CAS_PHASE_FETCH,
+    CAS_PHASE_PACK,
+    VAL_BATCHED_DEVICE,
+    VAL_BATCHED_FILE_BYTES,
+    VAL_BATCHED_FILES,
+    VAL_BATCHED_GRID_BYTES,
+    VAL_BATCHED_PACK,
+)
 from .blake3_batch import (  # noqa: F401 — re-exported for callers
     CHUNK_LEN,
     WORDS_PER_CHUNK,
@@ -228,7 +240,9 @@ def _bucket_b(B: int) -> int:
 def checksums_words_batched(blobs) -> list:
     """Full BLAKE3 digests (64-hex) of B byte strings in ONE device
     dispatch: rows padded to a shared power-of-two chunk grid, hashed by
-    the batch machinery (sharded over the mesh when >1 device).
+    the batch machinery (sharded over the mesh when >1 device). Its
+    pack and device phases, files and bytes are the validator's batched
+    path in the sd_validator_* families.
 
     This is the validator's dispatch amortizer (VERDICT r4 item 4):
     hashing one file per call pays a dispatch round trip per file —
@@ -241,27 +255,32 @@ def checksums_words_batched(blobs) -> list:
         return []
     from .blake3_batch import CHUNK_LEN, WORDS_PER_CHUNK, digests_to_hex
 
-    maxlen = max(len(b) for b in blobs)
-    C = max(1, -(-max(maxlen, 1) // CHUNK_LEN))
-    C = 1 << (C - 1).bit_length()   # pow2 → few compiled grids
     hasher, n_dev = sharded_hasher()
     if hasher is None:
         hasher = blake3_words
-    Bp = _bucket_b(B)
-    if n_dev > 1:
-        from ..parallel.mesh import pad_to_multiple
+    with tracing.phase("validator.phase/pack", VAL_BATCHED_PACK):
+        maxlen = max(len(b) for b in blobs)
+        C = max(1, -(-max(maxlen, 1) // CHUNK_LEN))
+        C = 1 << (C - 1).bit_length()   # pow2 → few compiled grids
+        Bp = _bucket_b(B)
+        if n_dev > 1:
+            from ..parallel.mesh import pad_to_multiple
 
-        Bp = pad_to_multiple(Bp, n_dev)
-    buf = np.zeros((Bp, C * CHUNK_LEN), dtype=np.uint8)
-    lengths = np.zeros((Bp,), dtype=np.int32)
-    for i, b in enumerate(blobs):
-        buf[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
-        lengths[i] = len(b)
-    words = buf.view("<u4").reshape(Bp, C, WORDS_PER_CHUNK)
+            Bp = pad_to_multiple(Bp, n_dev)
+        buf = np.zeros((Bp, C * CHUNK_LEN), dtype=np.uint8)
+        lengths = np.zeros((Bp,), dtype=np.int32)
+        for i, b in enumerate(blobs):
+            buf[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
+            lengths[i] = len(b)
+        words = buf.view("<u4").reshape(Bp, C, WORDS_PER_CHUNK)
+    VAL_BATCHED_FILES.inc(B)
+    VAL_BATCHED_FILE_BYTES.inc(int(lengths.sum()))
+    VAL_BATCHED_GRID_BYTES.inc(buf.nbytes)
     if flags.get("SDTPU_DISPATCH_LOG"):
         DISPATCH_LOG.append({"B": B, "Bp": Bp, "n_dev": n_dev, "C": C,
                              "kind": "checksum"})
-    with jit_registry.device_scope("cas.checksums"):
+    with jit_registry.device_scope("cas.checksums"), \
+            tracing.phase("validator.phase/device", VAL_BATCHED_DEVICE):
         digests = hasher(words, lengths)[:B]
         with jit_registry.io("cas.checksums"):
             return digests_to_hex(digests)
@@ -274,13 +293,17 @@ def checksums_words_batched(blobs) -> list:
 DISPATCH_LOG: list = []  # sdlint: ok[unbounded-growth] flag-gated diagnostic (SDTPU_DISPATCH_LOG=1): dryrun artifacts read the whole log, so it must not self-truncate
 
 
-def cas_ids_jax(payloads, sizes, payload_lens=None, hasher=None) -> list:
+def cas_ids_jax(payloads, sizes, payload_lens=None, hasher=None,
+                marks=None) -> list:
     """End-to-end device CAS: payload rows + sizes → 16-hex CAS IDs.
 
     With no explicit `hasher`, a multi-device host dispatches through
     the mesh-sharded program (batch padded to a devices-multiple so
     every shard gets equal rows); single-device hosts use the local
-    jit/Pallas path."""
+    jit/Pallas path. The host phases are counted in
+    sd_cas_phase_seconds_total (pack, dispatch, fetch) and annotated
+    on a running profiler capture; `marks`, when a list, receives the
+    (pack, dispatch, fetch) end times of this call."""
     n_dev = 1
     if hasher is None:
         hasher, n_dev = sharded_hasher()
@@ -293,21 +316,29 @@ def cas_ids_jax(payloads, sizes, payload_lens=None, hasher=None) -> list:
             hasher = (_donated_local
                       if flags.get("SDTPU_DONATE_BUFFERS")
                       else blake3_words)
-    words, lengths = build_cas_messages(payloads, sizes, payload_lens)
-    B = words.shape[0]
-    Bp = _bucket_b(B)
-    if n_dev > 1:
-        from ..parallel.mesh import pad_to_multiple
+    with tracing.phase("cas.phase/pack", CAS_PHASE_PACK) as pack:
+        words, lengths = build_cas_messages(payloads, sizes, payload_lens)
+        B = words.shape[0]
+        Bp = _bucket_b(B)
+        if n_dev > 1:
+            from ..parallel.mesh import pad_to_multiple
 
-        Bp = pad_to_multiple(Bp, n_dev)  # equal per-shard rows
-    if Bp != B:
-        words = np.concatenate(
-            [words, np.zeros((Bp - B,) + words.shape[1:], words.dtype)])
-        lengths = np.concatenate(
-            [lengths, np.zeros((Bp - B,), lengths.dtype)])
+            Bp = pad_to_multiple(Bp, n_dev)  # equal per-shard rows
+        if Bp != B:
+            words = np.concatenate(
+                [words, np.zeros((Bp - B,) + words.shape[1:], words.dtype)])
+            lengths = np.concatenate(
+                [lengths, np.zeros((Bp - B,), lengths.dtype)])
+    CAS_DEVICE_BYTES.inc(words.nbytes)
+    CAS_MESSAGE_BYTES.inc(int(lengths.sum()))
     if flags.get("SDTPU_DISPATCH_LOG"):
         DISPATCH_LOG.append({"B": B, "Bp": Bp, "n_dev": n_dev})
     with jit_registry.device_scope("cas.ids"):
-        digests = hasher(words, lengths)[:B]
-        with jit_registry.io("cas.ids"):
-            return digests_to_cas_ids(digests)
+        with tracing.phase("cas.phase/dispatch", CAS_PHASE_DISPATCH) as disp:
+            digests = hasher(words, lengths)[:B]
+        with tracing.phase("cas.phase/fetch", CAS_PHASE_FETCH) as fetch:
+            with jit_registry.io("cas.ids"):
+                ids = digests_to_cas_ids(digests)
+    if marks is not None:
+        marks.append((pack.t1, disp.t1, fetch.t1))
+    return ids

@@ -21,6 +21,10 @@ here computed without any single device ever holding the whole file).
 Shard capacity must be a power of two chunks so shard boundaries land on
 subtree boundaries; files that fit in a single shard take the ordinary
 batched path instead.
+
+This is the validator's streamed path: its read, pack, host_hash and
+device phases, files and bytes are counted under path="streamed" in the
+sd_validator_* families (telemetry.py).
 """
 
 from __future__ import annotations
@@ -33,6 +37,16 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import jit_registry
+from .. import tracing
+from ..telemetry import (
+    VAL_STREAMED_DEVICE,
+    VAL_STREAMED_FILE_BYTES,
+    VAL_STREAMED_FILES,
+    VAL_STREAMED_GRID_BYTES,
+    VAL_STREAMED_HOST_HASH,
+    VAL_STREAMED_PACK,
+    VAL_STREAMED_READ,
+)
 from .blake3_batch import CHUNK_LEN, WORDS_PER_CHUNK, tree_reduce
 from .blake3_jax import _chunk_cvs_scan
 
@@ -113,10 +127,13 @@ def make_sharded_checksum(mesh: Mesh,
         if n_chunks <= shard_chunks:
             # Fits one shard: the top stage would need ROOT handling the
             # sharded path deliberately never applies — use the batched
-            # single-lane path.
+            # single-lane path, on the host.
             from .blake3_batch import blake3_batch_np
 
-            return blake3_batch_np([data])[0]
+            VAL_STREAMED_GRID_BYTES.inc(n_chunks * CHUNK_LEN)
+            with tracing.phase("validator.phase/host_hash",
+                               VAL_STREAMED_HOST_HASH):
+                return blake3_batch_np([data])[0]
         if len(data) > capacity:
             raise ValueError(
                 f"data ({len(data)} B) exceeds mesh capacity "
@@ -125,11 +142,15 @@ def make_sharded_checksum(mesh: Mesh,
             raise ValueError(
                 "single-call path is int32-bounded at 2 GiB; use "
                 "StreamingShardedChecksum")
-        buf = np.zeros(capacity, dtype=np.uint8)
-        buf[:len(data)] = np.frombuffer(data, dtype=np.uint8)
-        words = buf.view("<u4").reshape(D * shard_chunks, WORDS_PER_CHUNK)
+        with tracing.phase("validator.phase/pack", VAL_STREAMED_PACK):
+            buf = np.zeros(capacity, dtype=np.uint8)
+            buf[:len(data)] = np.frombuffer(data, dtype=np.uint8)
+            words = buf.view("<u4").reshape(D * shard_chunks,
+                                            WORDS_PER_CHUNK)
+        VAL_STREAMED_GRID_BYTES.inc(capacity)
         sharding = NamedSharding(mesh, P("data", None))
-        with jit_registry.device_scope("seqhash.reduce"):
+        with jit_registry.device_scope("seqhash.reduce"), \
+                tracing.phase("validator.phase/device", VAL_STREAMED_DEVICE):
             words_dev = jax.device_put(jnp.asarray(words), sharding)
             n_tops = np.int32(-(-n_chunks // shard_chunks))
             zero = jnp.zeros((), jnp.uint32)
@@ -195,23 +216,30 @@ class StreamingShardedChecksum:
         return self._windows_done
 
     def update(self, data: bytes) -> "StreamingShardedChecksum":
-        self._buf += data
+        with tracing.phase("validator.phase/pack", VAL_STREAMED_PACK):
+            self._buf += data
         # Keep at least one byte buffered: the final window must be the
         # ROOT path in digest(), so a window is only folded when data
         # strictly beyond it has arrived.
         while len(self._buf) > self._window_bytes:
-            window = bytes(self._buf[:self._window_bytes])
-            del self._buf[:self._window_bytes]
-            self._push_window_cv(self._window_top(window))
+            with tracing.phase("validator.phase/pack", VAL_STREAMED_PACK):
+                window = bytes(self._buf[:self._window_bytes])
+                del self._buf[:self._window_bytes]
+            cv = self._window_top(window)
+            with tracing.phase("validator.phase/device", VAL_STREAMED_DEVICE):
+                self._push_window_cv(cv)
         return self
 
     def _window_top(self, data: bytes) -> list:
         """Device-reduce one window to its 8-word subtree-top CV."""
-        buf = np.zeros(self._window_bytes, dtype=np.uint8)
-        buf[:len(data)] = np.frombuffer(data, dtype=np.uint8)
-        words = buf.view("<u4").reshape(
-            self._window_chunks, WORDS_PER_CHUNK)
-        with jit_registry.device_scope("seqhash.reduce"):
+        with tracing.phase("validator.phase/pack", VAL_STREAMED_PACK):
+            buf = np.zeros(self._window_bytes, dtype=np.uint8)
+            buf[:len(data)] = np.frombuffer(data, dtype=np.uint8)
+            words = buf.view("<u4").reshape(
+                self._window_chunks, WORDS_PER_CHUNK)
+        VAL_STREAMED_GRID_BYTES.inc(self._window_bytes)
+        with jit_registry.device_scope("seqhash.reduce"), \
+                tracing.phase("validator.phase/device", VAL_STREAMED_DEVICE):
             words_dev = jax.device_put(jnp.asarray(words), self._sharding)
             n_chunks = max(1, -(-len(data) // CHUNK_LEN))
             n_tops = np.int32(-(-n_chunks // self._shard_chunks))
@@ -242,16 +270,17 @@ class StreamingShardedChecksum:
     def digest(self) -> bytes:
         from .blake3_ref import BLOCK_LEN as B3_BLOCK, IV, PARENT, ROOT, compress
 
+        with tracing.phase("validator.phase/pack", VAL_STREAMED_PACK):
+            tail = bytes(self._buf)
         if not self._stack:
             # Whole stream fit in one window: single-call ROOT path.
-            return make_sharded_checksum(
-                self._mesh, self._shard_chunks)(bytes(self._buf))
-        tail = bytes(self._buf)
+            return make_sharded_checksum(self._mesh, self._shard_chunks)(tail)
         cv = self._window_top(tail)
         # Finalize: fold the stack right-to-left; ROOT on the last parent.
-        for i, left in enumerate(reversed(self._stack)):
-            flags = PARENT | (ROOT if i == len(self._stack) - 1 else 0)
-            cv = compress(list(IV), left + cv, 0, B3_BLOCK, flags)[:8]
+        with tracing.phase("validator.phase/device", VAL_STREAMED_DEVICE):
+            for i, left in enumerate(reversed(self._stack)):
+                flags = PARENT | (ROOT if i == len(self._stack) - 1 else 0)
+                cv = compress(list(IV), left + cv, 0, B3_BLOCK, flags)[:8]
         return b"".join(int(w).to_bytes(4, "little") for w in cv)
 
     def hexdigest(self) -> str:
@@ -275,10 +304,15 @@ def sharded_file_checksum(mesh: Mesh, path: str,
     D = int(np.prod(mesh.devices.shape))
     window = D * shard_chunks * CHUNK_LEN
     h = StreamingShardedChecksum(mesh, shard_chunks)
+    size = 0
     with open(path, "rb") as f:
         while True:
-            block = f.read(window)
+            with tracing.phase("validator.phase/read", VAL_STREAMED_READ):
+                block = f.read(window)
             if not block:
                 break
+            size += len(block)
             h.update(block)
+    VAL_STREAMED_FILES.inc()
+    VAL_STREAMED_FILE_BYTES.inc(size)
     return h.hexdigest()

@@ -37,6 +37,7 @@ from . import cas, jit_registry
 from .. import channels, chaos, flags, persist, tracing
 from ..flight import RECORDER
 from ..telemetry import (
+    CAS_PHASE_STAGE,
     STAGE_BATCHES,
     STAGE_FALLBACK_FILES,
     STAGE_H2D_PROBE_FAILURES,
@@ -534,21 +535,39 @@ def _cas_ids_numpy(files, large, small) -> Dict[int, str]:
     return out
 
 
-def _cas_ids_jax(files, large, small) -> Dict[int, str]:
+def _cas_ids_jax(files, chunk: int):
+    """The jax backend's chunk: stage, then pack/dispatch/fetch per size
+    class (cas_ids_jax), each phase counted in sd_cas_phase_seconds_total
+    and annotated on a running profiler capture. The flight recorder's
+    identify lanes come from the same boundaries: `stage` is the reads
+    plus the class's packing, `h2d` the dispatch, `retire` the fetch; the
+    kernel itself is on the device trace, not on a host-clock lane."""
     from .blake3_jax import cas_ids_jax
-    out: Dict[int, str] = {}
+
+    trace = tracing.current_trace_id()
+    with tracing.phase("cas.phase/stage", CAS_PHASE_STAGE) as st:
+        large, small, empty_idx, errors = stage_files(files)
+    ids: Dict[int, Optional[str]] = {}
+    t = st.t0
     for batch in (large, small):
         if not batch.indexes:
             continue
-        ids = cas_ids_jax(batch.payloads, batch.sizes, batch.payload_lens)
-        out.update(zip(batch.indexes, ids))
-    return out
+        marks: List[Tuple[float, float, float]] = []
+        ids.update(zip(batch.indexes, cas_ids_jax(
+            batch.payloads, batch.sizes, batch.payload_lens, marks=marks)))
+        packed, dispatched, fetched = marks[0]
+        for lane, t0, t1 in (("stage", t, packed),
+                             ("h2d", packed, dispatched),
+                             ("retire", dispatched, fetched)):
+            RECORDER.record(lane, batch=chunk, t0=t0, t1=t1, device="jax",
+                            scope="identify", trace=trace, files=len(files))
+        t = fetched
+    return ids, empty_idx, errors
 
 
 _BACKENDS = {
     "oracle": _cas_ids_oracle,
     "numpy": _cas_ids_numpy,
-    "jax": _cas_ids_jax,
 }
 
 
@@ -815,27 +834,27 @@ def cas_ids_for_files(
     # additionally runs under the sanitizer's D2H transfer guard: the
     # only sanctioned fetch in this region is cas_ids_jax's declared
     # io("cas.ids") scope — anything else raises in tier-1.
-    from contextlib import nullcontext
-
-    guard = (jit_registry.device_scope(f"cas_ids/{backend}")
-             if backend == "jax" else nullcontext())
-    with device_span(f"cas_ids/{backend}", batch=len(files)), guard:
-        trace = tracing.current_trace_id()
-        t0 = time.perf_counter()
-        large, small, empty_idx, errors = stage_files(files)
-        t1 = time.perf_counter()
-        ids: Dict[int, Optional[str]] = dict(
-            _BACKENDS[backend](files, large, small))
-        # Host-plane chunks get the same stage/kernel lanes as the
-        # depth-N pipeline (scope "identify"): the exporter shows
-        # hash-ahead chunk cadence next to the device ring's lanes.
-        RECORDER.record("stage", batch=chunk, t0=t0, t1=t1,
-                        device=backend, scope="identify", trace=trace,
-                        files=len(files))
-        RECORDER.record("kernel", batch=chunk, t0=t1,
-                        t1=time.perf_counter(), device=backend,
-                        scope="identify", trace=trace,
-                        files=len(files))
+    if backend == "jax":
+        with device_span("cas_ids/jax", batch=len(files)), \
+                jit_registry.device_scope("cas_ids/jax"):
+            ids, empty_idx, errors = _cas_ids_jax(files, chunk)
+    else:
+        with device_span(f"cas_ids/{backend}", batch=len(files)):
+            trace = tracing.current_trace_id()
+            t0 = time.perf_counter()
+            large, small, empty_idx, errors = stage_files(files)
+            t1 = time.perf_counter()
+            ids = dict(_BACKENDS[backend](files, large, small))
+            # Host-plane chunks get the same stage/kernel lanes as the
+            # depth-N pipeline (scope "identify"): the exporter shows
+            # hash-ahead chunk cadence next to the device ring's lanes.
+            RECORDER.record("stage", batch=chunk, t0=t0, t1=t1,
+                            device=backend, scope="identify", trace=trace,
+                            files=len(files))
+            RECORDER.record("kernel", batch=chunk, t0=t1,
+                            t1=time.perf_counter(), device=backend,
+                            scope="identify", trace=trace,
+                            files=len(files))
     for idx in empty_idx:
         ids[idx] = None  # "We can't do shit with empty files" (mod.rs:86)
     for idx in errors:

@@ -23,9 +23,9 @@ Design constraints, in order:
   families registered anywhere else or on name collisions (since
   round 9 the lint is sdlint's telemetry pass; the shim remains).
   Names follow `sd_<layer>_<what>[_total|_seconds|_bytes]` with
-  layers jobs | identifier | sync | p2p | store | api | trace |
-  sanitize | jit | task | timeout | chan | health | sql | chaos |
-  backoff | wire.
+  layers jobs | identifier | cas | validator | sync | p2p | store |
+  api | event | trace | sanitize | jit | task | timeout | chan |
+  health | sql | chaos | backoff | wire.
 - **Windowed reads without resets.** Counters and histograms expose
   `snapshot_delta(cursor)` — an exact delta view since a previous
   cursor — so the health observatory (health.py) can compute windowed
@@ -517,6 +517,83 @@ IDENT_PHASE_SECONDS = counter(
     "Per-phase cost attribution of identifier steps (the phase_ms "
     "split, as live counters)", labelnames=("phase",))
 
+# -- device CAS path (ops/staging.py, ops/blake3_jax.py) --------------------
+CAS_PHASE_SECONDS = counter(
+    "sd_cas_phase_seconds_total",
+    "Host seconds of the device CAS path (cas_ids_for_files with "
+    "backend jax), by phase: stage (file reads into payload rows) | "
+    "pack (CAS messages built and padded to the batch bucket) | "
+    "dispatch (the jitted call with its host arguments, to its "
+    "return) | fetch (from that return until the CAS-ID strings "
+    "exist, unfinished device time included). Nested inside "
+    "sd_identifier_phase_seconds_total{phase=\"hash\"}",
+    labelnames=("phase",))
+CAS_PHASE_STAGE = CAS_PHASE_SECONDS.labels(phase="stage")
+CAS_PHASE_PACK = CAS_PHASE_SECONDS.labels(phase="pack")
+CAS_PHASE_DISPATCH = CAS_PHASE_SECONDS.labels(phase="dispatch")
+CAS_PHASE_FETCH = CAS_PHASE_SECONDS.labels(phase="fetch")
+CAS_DEVICE_BYTES = counter(
+    "sd_cas_device_bytes_total",
+    "Bytes of the word grids the device CAS path hands to the device, "
+    "after row padding (57 or 101 KiB chunks a row) and batch padding "
+    "(rows up to the bucket)")
+CAS_MESSAGE_BYTES = counter(
+    "sd_cas_message_bytes_total",
+    "CAS message bytes (8-byte size prefix plus payload) inside those "
+    "grids: sd_cas_device_bytes_total over this is the padding ratio")
+
+# -- validator device path (objects/validator.py, ops/blake3_jax.py
+#    checksums_words_batched, ops/seqhash.py) --------------------------------
+VALIDATOR_PHASE_SECONDS = counter(
+    "sd_validator_phase_seconds_total",
+    "Host seconds of ObjectValidatorJob's device checksum path "
+    "(backend jax), by path and phase. path=page holds the per-step "
+    "phases: rows (DB page fetch and the stat sweep that routes each "
+    "file) and compare (the verdict loop). path=batched (files up to "
+    "SMALL_FILE_CAP, one dispatch a page) and path=streamed (larger "
+    "files, one at a time) hold read (file reads), pack (grid and "
+    "window buffers filled, bytearray/bytes copies included) and "
+    "device (dispatch, device_put, reduce and digest fetch); "
+    "streamed host_hash is a file that fits one mesh window, hashed "
+    "by the numpy BLAKE3 on the host",
+    labelnames=("path", "phase"))
+VAL_PAGE_ROWS = VALIDATOR_PHASE_SECONDS.labels(path="page", phase="rows")
+VAL_PAGE_COMPARE = VALIDATOR_PHASE_SECONDS.labels(
+    path="page", phase="compare")
+VAL_BATCHED_READ = VALIDATOR_PHASE_SECONDS.labels(
+    path="batched", phase="read")
+VAL_BATCHED_PACK = VALIDATOR_PHASE_SECONDS.labels(
+    path="batched", phase="pack")
+VAL_BATCHED_DEVICE = VALIDATOR_PHASE_SECONDS.labels(
+    path="batched", phase="device")
+VAL_STREAMED_READ = VALIDATOR_PHASE_SECONDS.labels(
+    path="streamed", phase="read")
+VAL_STREAMED_PACK = VALIDATOR_PHASE_SECONDS.labels(
+    path="streamed", phase="pack")
+VAL_STREAMED_HOST_HASH = VALIDATOR_PHASE_SECONDS.labels(
+    path="streamed", phase="host_hash")
+VAL_STREAMED_DEVICE = VALIDATOR_PHASE_SECONDS.labels(
+    path="streamed", phase="device")
+VALIDATOR_BYTES = counter(
+    "sd_validator_bytes_total",
+    "Bytes through the validator's checksum paths: kind=file is the "
+    "files' own bytes, kind=grid the padded word grids they are hashed "
+    "in (batch rows padded to the page's power-of-two chunk count and "
+    "the bucket; streamed windows padded to the mesh window)",
+    labelnames=("path", "kind"))
+VAL_BATCHED_FILE_BYTES = VALIDATOR_BYTES.labels(path="batched", kind="file")
+VAL_BATCHED_GRID_BYTES = VALIDATOR_BYTES.labels(path="batched", kind="grid")
+VAL_STREAMED_FILE_BYTES = VALIDATOR_BYTES.labels(
+    path="streamed", kind="file")
+VAL_STREAMED_GRID_BYTES = VALIDATOR_BYTES.labels(
+    path="streamed", kind="grid")
+VALIDATOR_FILES = counter(
+    "sd_validator_files_total",
+    "Files checksummed by the validator's device path, by path",
+    labelnames=("path",))
+VAL_BATCHED_FILES = VALIDATOR_FILES.labels(path="batched")
+VAL_STREAMED_FILES = VALIDATOR_FILES.labels(path="streamed")
+
 # -- pipeline (ops/overlap.py depth-N identify pipeline) --------------------
 PIPELINE_DEPTH_HIGH_WATER = gauge(
     "sd_pipeline_depth_high_water",
@@ -722,6 +799,14 @@ SQL_SCAN = counter(
 API_REQUESTS = counter(
     "sd_api_requests_total", "HTTP requests served, by route template",
     labelnames=("route",))
+
+# -- event loop (node.py LoopLagProbe) ---------------------------------------
+EVENT_LOOP_LAG = histogram(
+    "sd_event_loop_lag_seconds",
+    "How late the node's event loop wakes a fixed 100 ms sleep: time "
+    "the loop spent in other callbacks past the due time (rspc "
+    "handlers that run on the loop, such as search.paths, show here)",
+    buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 5))
 
 # -- tracing (tracing.py, flight.py) ----------------------------------------
 TRACE_SPANS = counter(

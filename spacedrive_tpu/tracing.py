@@ -15,9 +15,12 @@ batches:
   distinguishable in logs and the ring buffer) and lands in a bounded
   ring of recent spans queryable at runtime (`recent_spans`, served by
   the `node.spans` rspc query);
-- when `SDTPU_PROFILE=/path` is set, `device_span(name)` additionally
-  wraps the block in a jax profiler trace so device batches show up in
-  TensorBoard/xprof with step markers;
+- every span, and every hot-loop `phase(name, counter)`, is also a
+  `jax.profiler.TraceAnnotation` whenever a profiler capture is
+  running, whoever started it: the program's spans land on the trace's
+  host plane, on the clock of the device ops. `SDTPU_PROFILE=/path`
+  makes the first `device_span(name)` start such a capture itself, so
+  device batches show up in TensorBoard/xprof;
 - spans PROPAGATE across nodes: `traceparent()` renders the current
   (trace, span) as a compact wire field, `continue_trace(tp)` adopts a
   remote caller's ids so the first span opened inside becomes a child
@@ -40,6 +43,7 @@ import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -399,6 +403,7 @@ def span(name: str, events=None, **fields):
         trace_id, parent_id = parent[0], parent[1]
     span_id = _new_id()
     token = _current_span.set((trace_id, span_id))
+    ann = _annotate(name)
     t0 = time.perf_counter()
     err: Optional[BaseException] = None
     try:
@@ -407,6 +412,8 @@ def span(name: str, events=None, **fields):
         err = e
         raise
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _current_span.reset(token)
         ms = (time.perf_counter() - t0) * 1000
         record = {
@@ -435,16 +442,68 @@ def span(name: str, events=None, **fields):
 
 @contextlib.contextmanager
 def device_span(name: str, events=None, **fields):
-    """span() + named jax profiler trace context when profiling is on."""
-    if _ensure_profiler():
-        import jax
+    """span() around a device batch, starting the SDTPU_PROFILE capture
+    first when that flag is set (span() itself annotates the capture)."""
+    _ensure_profiler()
+    with span(name, events, **fields):
+        yield
 
-        with jax.profiler.TraceAnnotation(name):
-            with span(name, events, **fields):
-                yield
-    else:
-        with span(name, events, **fields):
-            yield
+
+# -- profiler annotations and hot-loop phases --------------------------------
+
+_annotation_cls = None
+
+
+def _annotate(name: str):
+    """An entered jax.profiler.TraceAnnotation named `name` while a
+    profiler capture is running, whoever started it; else None. jax is
+    never imported here (a ~3 s import that processes which never touch
+    the device must not pay): a process that has not imported it has no
+    capture to annotate."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
+# Each instance lives inside one with-block on one thread; it is never
+# shared, so it needs no ownership contract.
+class phase:  # sdlint: ok[shared-mutation]
+    """Time one phase of a hot loop: `with phase("cas.phase/pack",
+    CAS_PHASE_PACK):` adds the block's seconds to the pre-bound counter
+    child and, under a running profiler capture, puts the block on the
+    trace's host plane as an annotation of the same name, on the clock
+    of the device ops. Two clock reads and no ring record, log line or
+    id: phases run per chunk, page and window, and must not evict the
+    job and rpc spans node.spans serves. `t0`/`t1` stay readable after
+    the block (the flight recorder's identify lanes reuse them)."""
+
+    __slots__ = ("name", "counter", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, counter) -> None:
+        self.name = name
+        self.counter = counter
+
+    def __enter__(self) -> "phase":
+        self._ann = _annotate(self.name)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self.counter.inc(self.t1 - self.t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 # ---------------------------------------------------------------------------
@@ -476,6 +535,12 @@ def declare_span(family: str, doc: str = "") -> str:
     SPAN_FAMILIES[family] = doc
     return family
 
+
+declare_span(
+    "cas.phase",
+    "One host phase of the device CAS path (tracing.phase, counted in "
+    "sd_cas_phase_seconds_total): stage | pack | dispatch | fetch, "
+    "nested in cas_ids/jax.")
 
 declare_span(
     "cas_ids",
@@ -529,3 +594,9 @@ declare_span(
     "The originator half of one sync stream (sync_net._originate_one): "
     "announce + serve the peer's pull loop; root of the cross-node "
     "sync trace.")
+
+declare_span(
+    "validator.phase",
+    "One host phase of ObjectValidatorJob's device checksum path "
+    "(tracing.phase, counted in sd_validator_phase_seconds_total): "
+    "rows | read | pack | host_hash | device | compare.")

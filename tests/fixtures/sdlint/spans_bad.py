@@ -1,9 +1,9 @@
 """sdlint fixture — telemetry-pass span-name KNOWN POSITIVES: an
-undeclared family, fully-dynamic names, and a declaration outside the
-central registry."""
+undeclared family, fully-dynamic names (span helpers and the phase
+helper alike), and a declaration outside the central registry."""
 
 import spacedrive_tpu.tracing as tr
-from spacedrive_tpu.tracing import declare_span, device_span
+from spacedrive_tpu.tracing import declare_span, device_span, phase
 from spacedrive_tpu.tracing import span as trace_span
 
 
@@ -47,6 +47,17 @@ def dynamic_name(name):
 
 def dynamic_prefix(name):
     with device_span(f"{name}/suffix"):  # family itself is dynamic
+        pass
+
+
+def undeclared_phase(counter):
+    # the phase helper's annotation names obey the same namespace
+    with tr.phase("rogue.phase/pack", counter):
+        pass
+
+
+def dynamic_phase(name, counter):
+    with phase(name, counter):
         pass
 
 

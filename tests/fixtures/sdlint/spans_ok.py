@@ -29,6 +29,12 @@ def aliased_module_call():
         pass
 
 
+def declared_phase(counter):
+    # the hot-loop phase helper: a declared family, literal variant
+    with tracing.phase("cas.phase/pack", counter):
+        pass
+
+
 def unrelated_span_function():
     def span(name):  # a local def named span is NOT the span surface
         return name

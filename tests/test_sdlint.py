@@ -140,6 +140,16 @@ def test_span_name_discipline_flags_known_positives():
     assert "declare_span" in by_code.get("span-central", set())
 
 
+def test_span_name_discipline_covers_the_phase_helper():
+    """tracing.phase() names profiler annotations from the same declared
+    families: an undeclared family and a non-literal name are findings."""
+    found = _lint_fixture("spans_bad.py", "telemetry")
+    assert any(f.code == "span-undeclared" and f.ident == "rogue.phase/pack"
+               for f in found), found
+    assert any(f.code == "span-dynamic" and f.ident == "phase"
+               for f in found), found
+
+
 def test_span_name_discipline_passes_known_negatives():
     """Declared families through every import spelling — including a
     dynamic VARIANT under a declared family, and a local function that

@@ -345,6 +345,32 @@ def test_identify_host_plane_records_timeline(tmp_path):
     assert flight.validate_chrome_trace(doc) == []
 
 
+def test_identify_jax_plane_records_phase_lanes(tmp_path):
+    """On the jax backend the identify lanes come from the phase
+    boundaries (stage+pack, dispatch, fetch): stage, h2d and retire,
+    back to back, and no host-clock kernel lane — the kernel is on the
+    device trace."""
+    from spacedrive_tpu.ops.staging import cas_ids_for_files
+
+    files = []
+    for i, size in enumerate((700, 5000)):
+        p = tmp_path / f"f{i}.bin"
+        p.write_bytes(bytes([i + 1]) * size)
+        files.append((str(p), size))
+    flight.RECORDER.clear()
+    ids, errors = cas_ids_for_files(files, backend="jax")
+    assert not errors and len(ids) == 2
+    snap = [e for e in flight.RECORDER.snapshot()
+            if e.get("scope") == "identify"]
+    assert [e["lane"] for e in snap] == ["stage", "h2d", "retire"]
+    assert all(e["device"] == "jax" for e in snap)
+    assert len({e["batch"] for e in snap}) == 1
+    for a, b in zip(snap, snap[1:]):
+        assert abs(a["ts_us"] + a["dur_us"] - b["ts_us"]) <= 2
+    doc = flight.chrome_trace(node_name="identify")
+    assert flight.validate_chrome_trace(doc) == []
+
+
 # -- cross-node propagation -------------------------------------------------
 
 def test_traceparent_round_trip_and_malformed():

@@ -10,7 +10,8 @@ docstring of the shim for the rule-by-rule rationale.
 Round 14 extends the same discipline to SPAN NAMES: a name passed to
 `span()`/`device_span()` (tracing.py) is `<family>` or
 `<family>/<variant>`, and the family must be declared via
-`declare_span()` at the bottom of spacedrive_tpu/tracing.py — the
+`declare_span()` at the bottom of spacedrive_tpu/tracing.py (the hot-loop
+`phase()` helper names its profiler annotations the same way) — the
 observable-name contract metric families already have, applied to the
 trace surface the flight recorder exports. Codes:
 
@@ -49,14 +50,14 @@ PASS = "telemetry"
 FACTORY_NAMES = {"counter", "gauge", "histogram"}
 CLASS_NAMES = {"Counter", "Gauge", "Histogram"}
 NAME_RE = re.compile(
-    r"^sd_(jobs?|identifier|sync|p2p|store|api|trace|sanitize|jit"
-    r"|task|timeout|chan|pipeline|stage|race|health|sql|fleet|obs"
-    r"|chaos|backoff|incident|persist|wire)"
+    r"^sd_(jobs?|identifier|cas|validator|sync|p2p|store|api|event"
+    r"|trace|sanitize|jit|task|timeout|chan|pipeline|stage|race|health"
+    r"|sql|fleet|obs|chaos|backoff|incident|persist|wire)"
     r"_[a-z0-9_]+$")
 
 CENTRAL_MODULE = "telemetry.py"
 
-SPAN_FUNCS = {"span", "device_span"}
+SPAN_FUNCS = {"span", "device_span", "phase"}
 SPAN_CENTRAL = "spacedrive_tpu/tracing.py"
 
 HEALTH_MODULE = "health.py"
@@ -339,8 +340,9 @@ class _Visitor(ast.NodeVisitor):
         if not NAME_RE.match(name):
             self.problems.append(
                 f"{where}: {name!r} breaks the naming scheme "
-                f"sd_<layer>_<what> (layers: jobs/identifier/sync/"
-                f"p2p/store/api/trace/sanitize/jit/task/timeout/chan/"
+                f"sd_<layer>_<what> (layers: jobs/identifier/cas/"
+                f"validator/sync/p2p/store/api/event/trace/sanitize/jit/"
+                f"task/timeout/chan/"
                 f"pipeline/stage/race/health/sql/fleet/obs/chaos/"
                 f"backoff/incident/persist/wire)")
 
