@@ -549,13 +549,14 @@ VALIDATOR_PHASE_SECONDS = counter(
     "Host seconds of ObjectValidatorJob's device checksum path "
     "(backend jax), by path and phase. path=page holds the per-step "
     "phases: rows (DB page fetch and the stat sweep that routes each "
-    "file) and compare (the verdict loop). path=batched (files up to "
-    "SMALL_FILE_CAP, one dispatch a page) and path=streamed (larger "
-    "files, one at a time) hold read (file reads), pack (grid and "
-    "window buffers filled, bytearray/bytes copies included) and "
-    "device (dispatch, device_put, reduce and digest fetch); "
-    "streamed host_hash is a file that fits one mesh window, hashed "
-    "by the numpy BLAKE3 on the host",
+    "file) and compare (the verdict loop). path=batched (files that "
+    "fit one streaming shard, SMALL_FILE_CAP at least; one dispatch a "
+    "page) and path=streamed (files spanning several shards, one at a "
+    "time) hold read (file reads), pack (grid and window buffers "
+    "filled, bytearray/bytes copies included) and device (dispatch, "
+    "device_put, reduce and digest fetch); streamed host_hash is a "
+    "file that fits one mesh window, hashed by the numpy BLAKE3 on "
+    "the host, which the validator's split no longer sends there",
     labelnames=("path", "phase"))
 VAL_PAGE_ROWS = VALIDATOR_PHASE_SECONDS.labels(path="page", phase="rows")
 VAL_PAGE_COMPARE = VALIDATOR_PHASE_SECONDS.labels(

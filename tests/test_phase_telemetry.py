@@ -6,6 +6,7 @@ import asyncio
 import glob
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -81,12 +82,16 @@ def test_validator_phases_cover_both_paths(tmp_path):
         """A cap low enough for a tiny corpus to take both paths."""
         SMALL_FILE_CAP = 4096
 
+    # On the suite's 8-device mesh a streaming shard is 1 MiB, so the
+    # split cap is 1 MiB: only the last file spans several shards.
+    cap = LowCap._stream_plan(len(jax.devices()))[2]
     src = tmp_path / "loc"
     src.mkdir()
-    sizes = [300, 1000, 4096, 5000, 20_000]
+    sizes = [300, 1000, 4096, 5000, 20_000, 1_250_000]
     _write_files(src, sizes)
-    small = [s for s in sizes if s <= LowCap.SMALL_FILE_CAP]
-    big = [s for s in sizes if s > LowCap.SMALL_FILE_CAP]
+    small = [s for s in sizes if s <= cap]
+    big = [s for s in sizes if s > cap]
+    assert small and big
     phase = {
         (labels["path"], labels["phase"]): c for labels, c in
         telemetry.VALIDATOR_PHASE_SECONDS.samples()}
@@ -120,15 +125,45 @@ def test_validator_phases_cover_both_paths(tmp_path):
     assert {("page", "rows"), ("page", "compare"), ("batched", "read"),
             ("batched", "pack"), ("batched", "device"),
             ("streamed", "read"), ("streamed", "pack")} <= moved, moved
-    # a streamed file that fits one mesh window is hashed on the host
-    assert ("streamed", "host_hash") in moved or (
-        "streamed", "device") in moved, moved
+    # every file that fits one shard is batched: nothing reaches the
+    # host hash of a single-shard stream
+    assert ("streamed", "host_hash") not in moved, moved
     assert telemetry.VAL_BATCHED_FILE_BYTES.value - b0["bf"] == sum(small)
     assert telemetry.VAL_STREAMED_FILE_BYTES.value - b0["sf"] == sum(big)
     assert telemetry.VAL_BATCHED_FILES.value - b0["bn"] == len(small)
     assert telemetry.VAL_STREAMED_FILES.value - b0["sn"] == len(big)
     assert telemetry.VAL_BATCHED_GRID_BYTES.value - b0["bg"] >= sum(small)
     assert telemetry.VAL_STREAMED_GRID_BYTES.value - b0["sg"] >= sum(big)
+
+
+def test_validator_routes_single_shard_files_to_the_batched_grid(tmp_path):
+    """With a 1 MiB streaming shard (8-device mesh), a 500 KB file above
+    SMALL_FILE_CAP is hashed on the batched grid and a 1.2 MiB file is
+    streamed; neither reaches the host hash, and both digests are
+    BLAKE3's."""
+    from spacedrive_tpu.objects.validator import ObjectValidatorJob
+    from spacedrive_tpu.ops.blake3_batch import blake3_batch_np
+
+    class LowCap(ObjectValidatorJob):
+        SMALL_FILE_CAP = 4096
+
+    assert LowCap._stream_plan(len(jax.devices()))[1:] == (1024, 1 << 20)
+    files = _write_files(tmp_path, [500_000, 1_250_000], seed=9)
+    counters = {"batched": telemetry.VAL_BATCHED_FILES,
+                "streamed": telemetry.VAL_STREAMED_FILES,
+                "host_hash": telemetry.VAL_STREAMED_HOST_HASH}
+    before = {k: c.value for k, c in counters.items()}
+    job = LowCap(location_id=1, backend="jax", mode="verify")
+    errors = []
+    got = {path: hx for _r, path, hx in job._checksums_jax(
+        [({"id": i}, path) for i, (path, _size) in enumerate(files)],
+        errors)}
+    assert not errors, errors
+    moved = {k: c.value - before[k] for k, c in counters.items()}
+    assert moved == {"batched": 1, "streamed": 1, "host_hash": 0}, moved
+    for path, _size in files:
+        with open(path, "rb") as f:
+            assert got[path] == blake3_batch_np([f.read()])[0].hex(), path
 
 
 def test_event_loop_lag_probe_sees_a_blocked_loop(tmp_path):

@@ -4,6 +4,8 @@ which only fills NULL checksums and never re-verifies)."""
 import asyncio
 import os
 
+import pytest
+
 from spacedrive_tpu.jobs.report import JobStatus
 from spacedrive_tpu.locations.indexer_job import IndexerJob
 from spacedrive_tpu.locations.manager import create_location
@@ -120,3 +122,17 @@ def test_verify_mode_clean_completes(tmp_path):
             assert await node.jobs.wait(jid) == JobStatus.COMPLETED
         await node.shutdown()
     _run(main())
+
+
+@pytest.mark.parametrize("n_devices,shard_chunks,cap", [
+    (1, 8192, 8 << 20), (2, 4096, 4 << 20),
+    (4, 2048, 4 << 20), (8, 1024, 4 << 20)])
+def test_stream_plan_caps_the_batched_path_at_one_shard(
+        n_devices, shard_chunks, cap):
+    """The batched/streamed split follows the streaming shard: on one
+    device a shard is 8 MiB, so every file up to 8 MiB is batched; from
+    four devices the shard is under SMALL_FILE_CAP, which holds."""
+    D, got_chunks, got_cap = ObjectValidatorJob._stream_plan(n_devices)
+    assert D == n_devices
+    assert got_chunks == shard_chunks
+    assert got_cap == cap
