@@ -735,11 +735,12 @@ declare_channel(
 declare_channel(
     "ops.stage.pool", 12, "block", "ops",
     "Native staging buffer pool checkout window (ops/staging.py "
-    "StagePool): each depth slot's packed H2D source page — a pooled, "
-    "page-aligned anonymous mapping the C plane stages straight into "
-    "and jax reads zero-copy — counts one item from acquire until its "
-    "batch RETIRES. Capacity bounds total pooled pages "
-    "(SDTPU_STAGE_POOL_BUFFERS narrows below it): the depth-8 ring + "
+    "StagePool): each packed H2D source page — a pooled, page-aligned "
+    "anonymous mapping the C plane stages straight into and jax reads "
+    "zero-copy — counts one item from acquire until its batch RETIRES "
+    "(its digests are fetched). Capacity bounds total pooled pages "
+    "(SDTPU_STAGE_POOL_BUFFERS narrows below it): the identifier's two "
+    "size-class pages per device chunk, the depth-8 overlap ring + "
     "warmup/calibration leases + slack. An exhausted pool degrades "
     "the batch to the Python staging path — it never allocates past "
     "the bound — and a burst past capacity is a chan_overflow "

@@ -286,53 +286,82 @@ def checksums_words_batched(blobs) -> list:
             return digests_to_hex(digests)
 
 
-# Dispatch observability: when SDTPU_DISPATCH_LOG=1, every cas_ids_jax
+# Dispatch observability: when SDTPU_DISPATCH_LOG=1, every cas_ids_words
 # call appends {"B", "Bp", "n_dev"} here — per-device shard balance is
 # Bp/n_dev by construction (batch padded to a devices-multiple), and
 # the dryrun/driver artifacts record it from this log.
 DISPATCH_LOG: list = []  # sdlint: ok[unbounded-growth] flag-gated diagnostic (SDTPU_DISPATCH_LOG=1): dryrun artifacts read the whole log, so it must not self-truncate
 
 
+def cas_hasher(hasher=None):
+    """(hasher, n_dev) of a CAS dispatch. With no explicit `hasher`, a
+    multi-device host dispatches through the mesh-sharded program over
+    n_dev devices; single-device hosts use the local jit/Pallas path.
+    An explicit `hasher` runs on one device."""
+    if hasher is not None:
+        return hasher, 1
+    hasher, n_dev = sharded_hasher()
+    if hasher is None:
+        # Single-device dispatch goes through the donated entry by
+        # default (SDTPU_DONATE_BUFFERS): the batch's staged device
+        # copy is recycled at kernel completion, not pinned until the
+        # CAS-ID fetch. Donation consumes the device copy only, never
+        # the host grid it was transferred from.
+        hasher = (_donated_local
+                  if flags.get("SDTPU_DONATE_BUFFERS")
+                  else blake3_words)
+    return hasher, n_dev
+
+
+def cas_rows(B: int, n_dev: int) -> int:
+    """Rows of the CAS grid that carries B messages: B's bucket, padded
+    to a multiple of n_dev so every shard gets equal rows."""
+    Bp = _bucket_b(B)
+    if n_dev > 1:
+        from ..parallel.mesh import pad_to_multiple
+
+        Bp = pad_to_multiple(Bp, n_dev)
+    return Bp
+
+
 def cas_ids_jax(payloads, sizes, payload_lens=None, hasher=None,
                 marks=None) -> list:
     """End-to-end device CAS: payload rows + sizes → 16-hex CAS IDs.
 
-    With no explicit `hasher`, a multi-device host dispatches through
-    the mesh-sharded program (batch padded to a devices-multiple so
-    every shard gets equal rows); single-device hosts use the local
-    jit/Pallas path. The host phases are counted in
-    sd_cas_phase_seconds_total (pack, dispatch, fetch) and annotated
-    on a running profiler capture; `marks`, when a list, receives the
-    (pack, dispatch, fetch) end times of this call."""
-    n_dev = 1
-    if hasher is None:
-        hasher, n_dev = sharded_hasher()
-        if hasher is None:
-            # Single-device dispatch goes through the donated entry by
-            # default (SDTPU_DONATE_BUFFERS): the batch's staged device
-            # copy is recycled at kernel completion, not pinned until
-            # the CAS-ID fetch below. The words/lengths built here are
-            # per-call temporaries, so consuming them is always safe.
-            hasher = (_donated_local
-                      if flags.get("SDTPU_DONATE_BUFFERS")
-                      else blake3_words)
+    Packs the rows into CAS messages padded to the bucket's row count
+    (cas.phase/pack), then dispatches and fetches them through
+    `cas_ids_words`. `marks`, when a list, receives the (pack,
+    dispatch, fetch) end times of this call."""
+    hasher, n_dev = cas_hasher(hasher)
     with tracing.phase("cas.phase/pack", CAS_PHASE_PACK) as pack:
         words, lengths = build_cas_messages(payloads, sizes, payload_lens)
         B = words.shape[0]
-        Bp = _bucket_b(B)
-        if n_dev > 1:
-            from ..parallel.mesh import pad_to_multiple
-
-            Bp = pad_to_multiple(Bp, n_dev)  # equal per-shard rows
+        Bp = cas_rows(B, n_dev)
         if Bp != B:
             words = np.concatenate(
                 [words, np.zeros((Bp - B,) + words.shape[1:], words.dtype)])
             lengths = np.concatenate(
                 [lengths, np.zeros((Bp - B,), lengths.dtype)])
+    return cas_ids_words(words, lengths, B, hasher, n_dev, pack.t1, marks)
+
+
+def cas_ids_words(words, lengths, B: int, hasher, n_dev: int,
+                  packed_at: float, marks=None) -> list:
+    """The dispatch and fetch half of the device CAS: a [Bp, C, 256]
+    uint32 message grid and its [Bp] int32 lengths → the CAS IDs of its
+    first B rows. Rows past B are padding, with length 0 (their digests
+    are discarded). The grid may be a pooled host page: it is read until
+    the digests are fetched, so the caller keeps it until this returns.
+
+    The host phases are counted in sd_cas_phase_seconds_total (dispatch,
+    fetch) and annotated on a running profiler capture; the grid's bytes
+    in sd_cas_device_bytes_total and its messages' in
+    sd_cas_message_bytes_total. `marks`, when a list, receives
+    (packed_at, dispatch end, fetch end)."""
     CAS_DEVICE_BYTES.inc(words.nbytes)
     CAS_MESSAGE_BYTES.inc(int(lengths.sum()))
     if flags.get("SDTPU_DISPATCH_LOG"):
-        DISPATCH_LOG.append({"B": B, "Bp": Bp, "n_dev": n_dev})
+        DISPATCH_LOG.append({"B": B, "Bp": words.shape[0], "n_dev": n_dev})
     with jit_registry.device_scope("cas.ids"):
         with tracing.phase("cas.phase/dispatch", CAS_PHASE_DISPATCH) as disp:
             digests = hasher(words, lengths)[:B]
@@ -340,5 +369,5 @@ def cas_ids_jax(payloads, sizes, payload_lens=None, hasher=None,
             with jit_registry.io("cas.ids"):
                 ids = digests_to_cas_ids(digests)
     if marks is not None:
-        marks.append((pack.t1, disp.t1, fetch.t1))
+        marks.append((packed_at, disp.t1, fetch.t1))
     return ids

@@ -37,6 +37,7 @@ from . import cas, jit_registry
 from .. import channels, chaos, flags, persist, tracing
 from ..flight import RECORDER
 from ..telemetry import (
+    CAS_PHASE_PACK,
     CAS_PHASE_STAGE,
     STAGE_BATCHES,
     STAGE_FALLBACK_FILES,
@@ -201,6 +202,19 @@ def _stage_files_native(
     return large_batch, small_batch, empty_idx, errors
 
 
+def _size_classes(
+    files: Sequence[Tuple[str, int]],
+) -> Tuple[List[int], List[int], List[int]]:
+    """Indexes of the large (> 100 KiB), small (1 B to 100 KiB) and empty
+    files, by declared size."""
+    large_idx = [i for i, (_, s) in enumerate(files)
+                 if s > cas.MINIMUM_FILE_SIZE]
+    small_idx = [i for i, (_, s) in enumerate(files)
+                 if 0 < s <= cas.MINIMUM_FILE_SIZE]
+    empty_idx = [i for i, (_, s) in enumerate(files) if s == 0]
+    return large_idx, small_idx, empty_idx
+
+
 def stage_files(
     files: Sequence[Tuple[str, int]],
 ) -> Tuple[StagedBatch, StagedBatch, List[int], Dict[int, str]]:
@@ -210,11 +224,7 @@ def stage_files(
     errors maps file index → message (unreadable files are skipped, the
     caller records them as non-fatal job errors — JobRunErrors semantics).
     """
-    large_idx = [i for i, (_, s) in enumerate(files)
-                 if s > cas.MINIMUM_FILE_SIZE]
-    small_idx = [i for i, (_, s) in enumerate(files)
-                 if 0 < s <= cas.MINIMUM_FILE_SIZE]
-    empty_idx = [i for i, (_, s) in enumerate(files) if s == 0]
+    large_idx, small_idx, empty_idx = _size_classes(files)
     errors: Dict[int, str] = {}
 
     from .. import native as _native
@@ -419,6 +429,7 @@ def _grid_for(payload_cap: int) -> Tuple[int, int]:
 def stage_batch_native(
     files: Sequence[Tuple[str, int]],
     pool: Optional[StagePool] = None,
+    rows: Optional[int] = None,
 ) -> Optional[NativeStaged]:
     """Stage a batch straight into a pooled packed buffer via the C
     plane, or None to degrade the WHOLE batch to the Python path
@@ -427,7 +438,13 @@ def stage_batch_native(
     read, injected EIO) degrade PER FILE: the Python reader retries
     into the same packed row, and only a row failing both lands in
     `errors`. Byte parity with stage_files + build_cas_messages is
-    pinned by tests/test_staging_native.py."""
+    pinned by tests/test_staging_native.py.
+
+    The page's row stride is the small class's grid when any file is
+    small, else the large class's. `rows` (default: one per file) sizes
+    the page for a padded batch: rows past len(files) are left as the
+    pool hands them over, stale bytes included, for a caller that
+    dispatches them with length 0."""
     mode = str(flags.get("SDTPU_STAGE_NATIVE") or "auto")
     if mode in ("off", "0", "no", "false"):
         return None
@@ -437,12 +454,15 @@ def stage_batch_native(
     n = len(files)
     if n == 0:
         return None
+    rows = n if rows is None else rows
+    if rows < n:
+        raise ValueError(f"stage_batch_native: {rows} rows for {n} files")
     sizes = np.array([s for _, s in files], dtype=np.uint64)
     any_small = bool(np.any((sizes > 0) & (sizes <= cas.MINIMUM_FILE_SIZE)))
     payload_cap = cas.MINIMUM_FILE_SIZE if any_small \
         else cas.LARGE_PAYLOAD_SIZE
     grid_c, stride = _grid_for(payload_cap)
-    lease = (pool or stage_buffer_pool()).acquire(n, stride)
+    lease = (pool or stage_buffer_pool()).acquire(rows, stride)
     if lease is None:
         return None  # bounded resource: degrade instead of growing
     try:
@@ -495,7 +515,7 @@ def stage_batch_native(
                 errors[r] = f"{path}: {e}"
                 row[8:] = 0
                 msg_lens[r] = 8
-        words = lease.arr.view("<u4").reshape(n, grid_c, 256)
+        words = lease.arr.view("<u4").reshape(rows, grid_c, 256)
         STAGE_BATCHES.labels(backend="native").inc()
         STAGE_NATIVE_BYTES.inc(int(msg_lens.sum()))
         if fallback:
@@ -536,32 +556,77 @@ def _cas_ids_numpy(files, large, small) -> Dict[int, str]:
 
 
 def _cas_ids_jax(files, chunk: int):
-    """The jax backend's chunk: stage, then pack/dispatch/fetch per size
-    class (cas_ids_jax), each phase counted in sd_cas_phase_seconds_total
-    and annotated on a running profiler capture. The flight recorder's
-    identify lanes come from the same boundaries: `stage` is the reads
-    plus the class's packing, `h2d` the dispatch, `retire` the fetch; the
-    kernel itself is on the device trace, not on a host-clock lane."""
-    from .blake3_jax import cas_ids_jax
+    """The jax backend's chunk. Each size class is staged once by the
+    native packed stager straight into its device grid, a pooled page of
+    cas_rows(n) rows at the class's stride (cas.phase/stage); what is
+    left before the dispatch is the lengths vector (cas.phase/pack).
+    A class the packed stager declines (no native plane, flag off, pool
+    exhausted) takes the classic path: stage_files, then cas_ids_jax's
+    build_cas_messages and bucket pad. Both paths dispatch and fetch
+    through cas_ids_words, each phase counted in
+    sd_cas_phase_seconds_total and annotated on a running profiler
+    capture. The flight recorder's identify lanes come from the same
+    boundaries: `stage` is the reads plus the class's packing, `h2d` the
+    dispatch, `retire` the fetch; the kernel itself is on the device
+    trace, not on a host-clock lane."""
+    from .blake3_jax import cas_hasher, cas_ids_jax, cas_ids_words, cas_rows
 
     trace = tracing.current_trace_id()
-    with tracing.phase("cas.phase/stage", CAS_PHASE_STAGE) as st:
-        large, small, empty_idx, errors = stage_files(files)
+    hasher, n_dev = cas_hasher()
+    large_idx, small_idx, empty_idx = _size_classes(files)
+    errors: Dict[int, str] = {}
     ids: Dict[int, Optional[str]] = {}
-    t = st.t0
-    for batch in (large, small):
-        if not batch.indexes:
-            continue
-        marks: List[Tuple[float, float, float]] = []
-        ids.update(zip(batch.indexes, cas_ids_jax(
-            batch.payloads, batch.sizes, batch.payload_lens, marks=marks)))
-        packed, dispatched, fetched = marks[0]
-        for lane, t0, t1 in (("stage", t, packed),
-                             ("h2d", packed, dispatched),
-                             ("retire", dispatched, fetched)):
-            RECORDER.record(lane, batch=chunk, t0=t0, t1=t1, device="jax",
-                            scope="identify", trace=trace, files=len(files))
-        t = fetched
+    # (indexes into `files`, NativeStaged or the classic StagedBatch)
+    classes: List[Tuple[List[int], object]] = []
+    try:
+        with tracing.phase("cas.phase/stage", CAS_PHASE_STAGE) as st:
+            for idx in (large_idx, small_idx):
+                if not idx:
+                    continue
+                class_files = [files[i] for i in idx]
+                packed = stage_batch_native(
+                    class_files, rows=cas_rows(len(idx), n_dev))
+                if packed is not None:
+                    errors.update(
+                        (idx[r], msg) for r, msg in packed.errors.items())
+                    classes.append((idx, packed))
+                    continue
+                STAGE_BATCHES.labels(backend="python").inc()
+                large, small, _empty, errs = stage_files(class_files)
+                errors.update((idx[r], msg) for r, msg in errs.items())
+                batch = large if large.indexes else small
+                if batch.indexes:
+                    classes.append(([idx[r] for r in batch.indexes], batch))
+        t = st.t0
+        for idx, staged in classes:
+            marks: List[Tuple[float, float, float]] = []
+            if isinstance(staged, NativeStaged):
+                with tracing.phase("cas.phase/pack", CAS_PHASE_PACK) as pack:
+                    lengths = np.zeros((staged.words.shape[0],), np.int32)
+                    lengths[:len(idx)] = staged.lengths
+                    # A row both readers failed carries no message: its
+                    # ID is dropped, and its bytes count nowhere.
+                    lengths[list(staged.errors)] = 0
+                cids = cas_ids_words(staged.words, lengths, len(idx),
+                                     hasher, n_dev, pack.t1, marks)
+            else:
+                cids = cas_ids_jax(staged.payloads, staged.sizes,
+                                   staged.payload_lens, marks=marks)
+            ids.update(zip(idx, cids))
+            packed_at, dispatched, fetched = marks[0]
+            for lane, t0, t1 in (("stage", t, packed_at),
+                                 ("h2d", packed_at, dispatched),
+                                 ("retire", dispatched, fetched)):
+                RECORDER.record(lane, batch=chunk, t0=t0, t1=t1,
+                                device="jax", scope="identify", trace=trace,
+                                files=len(files))
+            t = fetched
+    finally:
+        # The pages are the transfers' host sources: they go back to the
+        # pool only once the digests are fetched, or the dispatch failed.
+        for _idx, staged in classes:
+            if isinstance(staged, NativeStaged):
+                staged.release()
     return ids, empty_idx, errors
 
 

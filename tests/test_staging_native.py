@@ -314,3 +314,122 @@ def test_stage_native_flag_modes(tmp_path, monkeypatch):
     for mode in ("off", "0", "no", "false"):
         monkeypatch.setenv("SDTPU_STAGE_NATIVE", mode)
         assert staging.stage_batch_native(files) is None, mode
+
+
+# -- the identifier's jax backend through the packed path ------------------
+#
+# cas_ids_for_files(backend="jax") stages each size class once, straight
+# into a pooled page of the class's bucketed rows. At most 8 files a
+# class keeps every batch on the two (8, C, 256) grids, compiled once.
+
+
+def _in_use(pool) -> int:
+    with pool._lock:
+        return pool._total - len(pool._free)
+
+
+def _stage_batches():
+    from spacedrive_tpu.telemetry import STAGE_BATCHES
+
+    return {b: STAGE_BATCHES.labels(backend=b).value
+            for b in ("native", "python")}
+
+
+def _mixed_batch(root, seed: int):
+    """Small, large and empty files plus a vanished one (declared 7,000
+    bytes, never written) and one that grew past the small class
+    (declared 5,000, holds 100 KiB + 600)."""
+    os.makedirs(root, exist_ok=True)
+    files = []
+    for i, n in enumerate((300, 4242, cas.MINIMUM_FILE_SIZE, 102401,
+                           150000, 0)):
+        p = os.path.join(root, f"m{i}.bin")
+        files.append((p, _write(p, _pattern(n, seed=seed + i))))
+    files.append((os.path.join(root, "gone.bin"), 7000))
+    grew = os.path.join(root, "grew.bin")
+    _write(grew, _pattern(cas.MINIMUM_FILE_SIZE + 600, seed=seed + 9))
+    files.append((grew, 5000))
+    return files
+
+
+@requires_native
+def test_jax_backend_packed_path_parity_on_dirty_pages(tmp_path,
+                                                       monkeypatch):
+    """CAS IDs through the packed path equal the oracle's, on pooled
+    pages a batch of other files left dirty, and the failed files are
+    the classic path's (SDTPU_STAGE_NATIVE=off)."""
+    pool = staging.StagePool()
+    monkeypatch.setattr(staging, "stage_buffer_pool", lambda: pool)
+    monkeypatch.setenv("SDTPU_STAGE_NATIVE", "on")
+    dirty = []
+    for i, n in enumerate([120000] * 8 + [90000] * 8):
+        p = str(tmp_path / f"dirty{i}.bin")
+        dirty.append((p, _write(p, _pattern(n, seed=40 + i))))
+    ids, errors = staging.cas_ids_for_files(dirty, backend="jax")
+    assert not errors and len(ids) == len(dirty)
+    assert pool._total == 2 and _in_use(pool) == 0
+
+    files = _mixed_batch(str(tmp_path / "mixed"), seed=60)
+    ids, errors = staging.cas_ids_for_files(files, backend="jax")
+    assert pool._total == 2, "the dirty pages were not reused"
+    assert sorted(errors) == [6, 7]
+    assert ids[5] is None  # the empty file gets no CAS ID
+    for i, (p, size) in enumerate(files):
+        if i in errors or size == 0:
+            assert i not in ids or ids[i] is None
+            continue
+        assert ids[i] == cas.generate_cas_id(p, size), i
+
+    monkeypatch.setenv("SDTPU_STAGE_NATIVE", "off")
+    ids_off, errors_off = staging.cas_ids_for_files(files, backend="jax")
+    assert sorted(errors_off) == sorted(errors)
+    assert ids_off == ids
+
+
+@requires_native
+@pytest.mark.parametrize("mode,grows", [("on", "native"), ("off", "python")])
+def test_jax_backend_stages_each_class_once(tmp_path, monkeypatch, mode,
+                                            grows):
+    """A two-class batch is two staged batches: both packed (native)
+    with the flag on, both classic (python) with it off."""
+    monkeypatch.setenv("SDTPU_STAGE_NATIVE", mode)
+    files = []
+    for i, n in enumerate((700, 120000, 5000, 130000)):
+        p = str(tmp_path / f"e{i}.bin")
+        files.append((p, _write(p, _pattern(n, seed=80 + i))))
+    before = _stage_batches()
+    ids, errors = staging.cas_ids_for_files(files, backend="jax")
+    assert not errors
+    assert all(ids[i] == cas.generate_cas_id(p, n)
+               for i, (p, n) in enumerate(files))
+    after = _stage_batches()
+    other = "python" if grows == "native" else "native"
+    assert after[grows] - before[grows] == 2
+    assert after[other] == before[other]
+
+
+@requires_native
+def test_jax_backend_returns_its_pages(tmp_path, monkeypatch):
+    """The pooled pages go back to the pool once the digests are
+    fetched, and when the dispatch raises."""
+    from spacedrive_tpu.ops import blake3_jax
+
+    pool = staging.StagePool()
+    monkeypatch.setattr(staging, "stage_buffer_pool", lambda: pool)
+    monkeypatch.setenv("SDTPU_STAGE_NATIVE", "on")
+    files = []
+    for i, n in enumerate((2000, 140000)):
+        p = str(tmp_path / f"l{i}.bin")
+        files.append((p, _write(p, _pattern(n, seed=90 + i))))
+    ids, errors = staging.cas_ids_for_files(files, backend="jax")
+    assert not errors and len(ids) == 2
+    assert pool._total == 2 and _in_use(pool) == 0
+
+    def failing_hasher(words, lengths):
+        raise RuntimeError("dispatch failed")
+
+    monkeypatch.setattr(blake3_jax, "cas_hasher",
+                        lambda hasher=None: (failing_hasher, 1))
+    with pytest.raises(RuntimeError, match="dispatch failed"):
+        staging.cas_ids_for_files(files, backend="jax")
+    assert pool._total == 2 and _in_use(pool) == 0
