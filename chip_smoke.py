@@ -20,11 +20,10 @@ references that share no device code with the path under test:
   Pallas BLAKE3 kernel traced (not the jnp scan).
 
 Four chips (--chips 4): the paths that exist only across chips — the
-mesh-sharded CAS hasher (through the identifier job), the depth-N
-device ring over all local devices, the validator's sequence-sharded
-checksum mesh and the 2x2 Hamming tile mesh — each compared with the
-native plane or numpy on the same corpus, and each shown to use all
-four devices.
+mesh-sharded CAS hasher (through the identifier job), the validator's
+sequence-sharded checksum mesh and the 2x2 Hamming tile mesh — each
+compared with the native plane or numpy on the same corpus, and each
+shown to use all four devices.
 
 Earlier lines are JSON records (device, per-phase and compile seconds,
 the H2D rate the link probe saw, peak device bytes, per-device batch
@@ -552,14 +551,10 @@ def smoke_four_chips(args, plan, work, lib_dir, compiles, phase) -> None:
     import jax
     import numpy as np
 
-    from spacedrive_tpu import native
     from spacedrive_tpu.locations.indexer_job import IndexerJob
     from spacedrive_tpu.objects.identifier import FileIdentifierJob
     from spacedrive_tpu.objects.validator import ObjectValidatorJob
-    from spacedrive_tpu.ops import overlap
-    from spacedrive_tpu.ops.blake3_jax import (DISPATCH_LOG,
-                                               digests_to_cas_ids,
-                                               sharded_hasher)
+    from spacedrive_tpu.ops.blake3_jax import DISPATCH_LOG, sharded_hasher
     from spacedrive_tpu.ops.hamming import make_sharded_hamming
     from spacedrive_tpu.ops.phash import phash_files
     from spacedrive_tpu.parallel.mesh import tile_mesh
@@ -594,26 +589,6 @@ def smoke_four_chips(args, plan, work, lib_dir, compiles, phase) -> None:
         raise AssertionError(f"CAS dispatches did not shard over all "
                              f"{len(devs)} chips: {DISPATCH_LOG[:4]}")
 
-    with phase("device_ring") as extra:
-        # The depth-N ring over every local device, on the corpus's
-        # sampled-CAS files in equal batches (one program shape).
-        B = 256
-        large = sorted((r[0], r[1]) for r in rows if r[1] > 100 << 10)
-        n = (len(large) // B) * B
-        batches = [([p for p, _ in large[i:i + B]],
-                    np.array([s for _, s in large[i:i + B]], np.uint64))
-                   for i in range(0, n, B)]
-        digests, stats = overlap.run_overlapped(batches, devices=devs)
-        ids = [c for d in digests for c in digests_to_cas_ids(d)]
-        want, status = native.cas_digests(
-            [p for p, _ in large[:n]],
-            np.array([s for _, s in large[:n]], np.uint64))
-        if (status != native.OK).any() or ids != [
-                w.tobytes().hex()[:16] for w in want]:
-            raise AssertionError("device ring CAS IDs differ from native")
-        extra["files"] = n
-        extra["per_device_batches"] = stats.per_device_batches
-
     with phase("hamming_tile_mesh") as extra:
         paths = sorted(
             os.path.join(d, f)
@@ -642,15 +617,12 @@ def smoke_four_chips(args, plan, work, lib_dir, compiles, phase) -> None:
         extra["seqhash_files"] = len(big)
 
     per_device = dispatch_counts()
-    ring = stats.per_device_batches
-    if (len(per_device) != len(devs) or len(ring) != len(devs)
-            or len(used) != len(devs)):
+    if len(per_device) != len(devs) or len(used) != len(devs):
         raise AssertionError(
-            f"not every chip was used: sharded {per_device}, ring {ring}, "
+            f"not every chip was used: sharded {per_device}, "
             f"hamming {sorted(used)}")
     emit({"phase": "device",
           "sharded_cas_per_device_batches": per_device,
-          "ring_per_device_batches": ring,
           "hamming_devices": sorted(used),
           "peak_bytes_in_use": [
               (dv.memory_stats() or {}).get("peak_bytes_in_use")
@@ -671,7 +643,6 @@ def main(argv=None) -> int:
     # for the per-device counts.
     os.environ["SDTPU_DEVICE_PIPELINE"] = "force"
     os.environ["SDTPU_DISPATCH_LOG"] = "1"
-    os.environ["SDTPU_PIPELINE_DEVICES"] = "0"
 
     from spacedrive_tpu.xla_env import enable_compile_cache
 

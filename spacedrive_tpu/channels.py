@@ -254,9 +254,8 @@ class Channel(_Metered):
         if capacity_cap is not None:
             # Runtime narrowing BELOW the declared ceiling is allowed —
             # the contract is the upper bound the registry audits, not
-            # an exact size (the depth-N overlap pipeline sizes its
-            # hand-off channels to the configured depth, which must
-            # never exceed the declared ops.pipeline.* capacity).
+            # an exact size (the API server narrows its in-flight
+            # channel to the configured request cap).
             self.capacity = max(1, min(self.capacity, int(capacity_cap)))
         self._on_evict = on_evict
         # Slots are [key, item] lists so a coalesce replacement mutates
@@ -707,28 +706,10 @@ declare_channel(
     "released) instead of absorbing the index into RAM.")
 
 declare_channel(
-    "ops.pipeline.inflight", 8, "block", "ops",
-    "Depth-N identify pipeline dispatched-but-unretired window "
-    "(ops/overlap.py): device digests (plus, on the undonated path, "
-    "their pinned input buffers) waiting for the D2H retirer. "
-    "Capacity is the SDTPU_PIPELINE_DEPTH ceiling; each run narrows "
-    "its instance to the configured depth.",
-    put_budget="ops.pipeline.inflight.put")
-
-declare_channel(
-    "ops.pipeline.staged", 8, "block", "ops",
-    "Depth-N identify pipeline staged-batch hand-off (ops/overlap.py): "
-    "host word/length arrays staged by the concurrent stagers, waiting "
-    "for a per-device dispatcher. Capacity is the SDTPU_PIPELINE_DEPTH "
-    "ceiling; each run narrows its instance to the configured depth.",
-    put_budget="ops.pipeline.staged.put")
-
-declare_channel(
     "ops.pipeline.timeline", 4096, "shed_oldest", "ops",
     "Flight-recorder timeline ring (spacedrive_tpu/flight.py): one "
-    "event per pipeline batch phase (stage/H2D/kernel/retire, plus "
-    "the per-batch bound-attribution window), written by the per-"
-    "device dispatch executor threads under the recorder's lock. "
+    "event per identify chunk phase (stage/H2D/kernel/retire), "
+    "written by the hashing threads under the recorder's lock. "
     "History ages out oldest-first — the export shows the recent "
     "window, memory never grows with uptime.", sheds_expected=True)
 
@@ -740,8 +721,8 @@ declare_channel(
     "zero-copy — counts one item from acquire until its batch RETIRES "
     "(its digests are fetched). Capacity bounds total pooled pages "
     "(SDTPU_STAGE_POOL_BUFFERS narrows below it): the identifier's two "
-    "size-class pages per device chunk, the depth-8 overlap ring + "
-    "warmup/calibration leases + slack. An exhausted pool degrades "
+    "size-class pages per device chunk, the hash-ahead worker's next "
+    "chunk, and slack. An exhausted pool degrades "
     "the batch to the Python staging path — it never allocates past "
     "the bound — and a burst past capacity is a chan_overflow "
     "violation.", kind="window")

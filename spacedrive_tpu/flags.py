@@ -202,30 +202,14 @@ declare(
 
 declare(
     "SDTPU_DONATE_BUFFERS", True, parse_onoff,
-    "Kill switch for donated device buffers on the identify pipelines "
-    "(ops/overlap.py ring, ops/blake3_jax.py donated CAS dispatch): "
-    "donated kernels consume their staged input buffers at dispatch so "
+    "Kill switch for donated device buffers on the identify path "
+    "(ops/blake3_jax.py cas_hasher's donated CAS dispatch): the "
+    "donated program consumes its staged input buffers at dispatch so "
     "each batch's H2D lands in recycled allocator space instead of "
-    "growing the in-flight footprint. `off` pins the undonated "
-    "programs (the CPU-mesh test suite sets it to dodge a ~45 s "
-    "duplicate compile per kernel variant; dedicated donation tests "
-    "flip it back with cheap kernels).")
-
-declare(
-    "SDTPU_PIPELINE_DEPTH", 3, parse_int,
-    "Batches in flight (stage→H2D→kernel→fetch) in the depth-N "
-    "identify pipeline (ops/overlap.py). 1 = fully serial; clamped to "
-    "the declared `ops.pipeline.*` channel capacity (8). Depth is the "
-    "ring-slot count: staged host batches, in-transfer buffers, and "
-    "undonated device inputs are all bounded by it.", strict=True)
-
-declare(
-    "SDTPU_PIPELINE_DEVICES", 0, parse_int,
-    "Cap on local devices the depth-N pipeline round-robins batches "
-    "across (ops/overlap.py via parallel/mesh.device_ring). 0 = all "
-    "local devices; the CPU-mesh test suite pins 1 so the virtual "
-    "8-device mesh doesn't pay a per-device kernel compile.",
-    strict=True)
+    "growing the in-flight footprint, and the identifier hashes ahead "
+    "on device-engaged runs only with it on. `off` pins the undonated "
+    "program (the CPU-mesh test suite sets it to dodge a ~45 s "
+    "duplicate compile per kernel variant).")
 
 declare(
     "SDTPU_HEALTH_INTERVAL_S", 5.0, parse_float,
@@ -369,15 +353,6 @@ declare(
     "dodge a ~50s shard_map compile per batch grid).")
 
 declare(
-    "SDTPU_SIM_LINK_GBPS", None, parse_float,
-    "Deterministic simulated H2D link for the depth-N pipeline "
-    "(ops/overlap.py): every host→device transfer additionally sleeps "
-    "nbytes / (rate·1e9) seconds, per device stream, so CPU-only "
-    "hosts (tier-1, tools/overlap_bench.py) can pin the overlap math "
-    "— measured rate vs the max(stage, h2d, kernel) bound — without "
-    "TPU hardware. Unset = real link only.")
-
-declare(
     "SDTPU_SPAN_RING", 512, parse_int,
     "Capacity of the tracing span ring buffer (tracing.py "
     "recent_spans / node.spans / the flight-recorder export). Read "
@@ -397,8 +372,8 @@ declare(
     "one flag, the whole native staging plane). Fails closed to the "
     "Python path "
     "when the shared object is missing, per-file on bad rows "
-    "(ENOENT/EACCES/short read). Read per batch, so benches can A/B "
-    "backends mid-process (tools/overlap_bench.py --staging).")
+    "(ENOENT/EACCES/short read). Read per batch, so a process can A/B "
+    "the backends.")
 
 declare(
     "SDTPU_STAGE_POOL_BUFFERS", 0, parse_int,

@@ -1,23 +1,17 @@
-"""Flight recorder: pipeline timeline capture + Chrome-trace export.
+"""Flight recorder: identify timeline capture + Chrome-trace export.
 
-The observability gap this closes (ROADMAP item 1's evidence problem):
-when an identify run misses its computed bound, the aggregate
-`sd_pipeline_*` counters say THAT time was lost, never WHERE — which
-batch, which device stream, which stage. The recorder keeps a bounded
-per-batch timeline of the depth-N pipeline (ops/overlap.py) and the
-host hashing planes (ops/staging.py): one event per
-stage/H2D/kernel/retire phase with begin-end wall timestamps, device
-and stream labels, and the owning trace id — plus one `window` event
-per retired batch carrying **bound attribution**: which of
-max(t_stage, t_h2d, t_kernel) was binding for that batch and by how
-much.
+Aggregate counters say THAT identify time was spent, never WHERE —
+which chunk, which backend, which phase. The recorder keeps a bounded
+per-chunk timeline of the host hashing planes and the jax backend
+(ops/staging.py): one event per stage/H2D/kernel/retire phase with
+begin-end wall timestamps, the backend label, and the owning trace id.
 
 Storage is a declared registry channel (`ops.pipeline.timeline`,
 shed_oldest — history ages out, memory never grows with uptime),
-written from the per-device dispatch executor threads and the pipeline
-coroutines under the recorder's lock; the ownership contract is
-declared in threadctx.py (`flight.FlightRecorder`) so the race
-recorder audits every write in tier-1.
+written from the identifier's hashing threads under the recorder's
+lock; the ownership contract is declared in threadctx.py
+(`flight.FlightRecorder`) so the race recorder audits every write in
+tier-1.
 
 `chrome_trace()` turns the span ring (tracing.py) plus this timeline
 into a Chrome-trace/Perfetto `traceEvents` JSON document —
@@ -25,12 +19,12 @@ per-device stage/H2D/kernel/retire lanes, span lanes grouped by trace
 id, `M` metadata naming every pid/tid — and `validate_chrome_trace()`
 is the schema gate: `tools/trace_export.py --json` self-checks through
 it in tier-1, the `node.trace.export` rspc route serves it from a live
-node, and `overlap_bench --trace` / `perf_smoke --trace` ship it next
-to their BENCH artifacts. `fleet_chrome_trace()` is the multi-node
-composition (fleet.py distributed trace assembly): N nodes' captures
-as per-node pid-lane pairs on one skew-aligned axis, offsets recorded
-in the document metadata, behind `fleet.trace.export` and
-`tools/trace_export.py --fleet`.
+node, and `perf_smoke --trace` ships it next to its artifact.
+`fleet_chrome_trace()` is the multi-node composition (fleet.py
+distributed trace assembly): N nodes' captures as per-node pid-lane
+pairs on one skew-aligned axis, offsets recorded in the document
+metadata, behind `fleet.trace.export` and `tools/trace_export.py
+--fleet`.
 
 Design constraints: stdlib + channels/telemetry/tracing only — every
 layer (ops executors, benches, the API host) can import it without
@@ -39,7 +33,6 @@ cycles and without jax.
 
 from __future__ import annotations
 
-import itertools
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -51,72 +44,31 @@ __all__ = [
     "fleet_chrome_trace", "validate_chrome_trace",
 ]
 
-# The pipeline phases one batch moves through, in order. `window` is
-# the synthetic fifth lane: emitted when a batch's `retire` lands,
-# carrying the batch's bound attribution.
+# The pipeline phases one batch moves through, in order.
 LANES = ("stage", "h2d", "kernel", "retire")
-
-# The three components the steady-state bound maximizes over
-# (PipelineStats.bound_files_per_sec) — per-batch attribution names
-# the binding one.
-_BOUND_COMPONENTS = ("stage", "h2d", "kernel")
-
-# Open-window safety cap: a run that dies mid-batch (or a caller that
-# records phases but never a retire) must not leak entries — past the
-# cap the oldest open window is dropped, not the recorder's memory
-# contract. Bounded well above any real in-flight depth (ring depth
-# caps at MAX_PIPELINE_DEPTH = 8 per run).
-_OPEN_CAP = 64
-
-# Run tokens disambiguate concurrent/successive pipeline runs whose
-# batch NUMBERING overlaps (two identifier jobs both dispatch a
-# "batch 3"; a trace id is not enough — one job's trace covers every
-# run it starts). new_run_token() is what run_overlapped threads
-# through its records.
-_RUN_SEQ = itertools.count(1)
-
-
-def new_run_token() -> int:
-    """Fresh per-run id for record(..., run=token): keeps one run's
-    open batch windows from colliding with another's."""
-    return next(_RUN_SEQ)
 
 
 class FlightRecorder:
     """Bounded per-batch pipeline timeline.
 
-    Writers are the per-device dispatch executor threads, the retire
-    executor thread, and the pipeline's private-loop coroutines —
-    every mutation runs under `_lock` (contract declared in
-    threadctx.py). Events are JSON-safe dicts; the ring is the
-    declared `ops.pipeline.timeline` channel, so capacity scales with
-    SDTPU_CHAN_SCALE and shed counts surface as
+    Writers are the identifier's hashing threads (its step thread and
+    hash-ahead worker) — every ring put runs under `_lock` (contract
+    declared in threadctx.py). Events are JSON-safe dicts; the ring is
+    the declared `ops.pipeline.timeline` channel, so capacity scales
+    with SDTPU_CHAN_SCALE and shed counts surface as
     sd_chan_shed_total{ops.pipeline.timeline}.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self.ring = channels.channel("ops.pipeline.timeline")
-        # (scope, run, batch) -> lane -> (t0_perf, t1_perf): the open
-        # batch windows awaiting their retire event. Entries leave at
-        # retire, and a window is only OPENED when the caller passes a
-        # run token (the pipeline loop, which always retires) — scopes
-        # that never emit a retire (identify host-plane chunks) are
-        # pure lane events, so they cannot accumulate here. Capped at
-        # _OPEN_CAP as the crashed-run backstop.
-        self._open: Dict[Tuple[str, int, int],
-                         Dict[str, Tuple[float, float]]] = {}
 
     def record(self, lane: str, batch: int, t0: float, t1: float,
                device: str = "", stream: int = 0,
                trace: Optional[str] = None, scope: str = "pipeline",
-               run: Optional[int] = None, **fields: Any) -> None:
+               **fields: Any) -> None:
         """One phase of one batch: [t0, t1) perf_counter readings from
-        the thread that ran the phase. With a `run` token
-        (new_run_token(); the pipeline loop passes one), phases
-        accumulate into the (scope, run, batch) window and `retire`
-        closes it, emitting the bound-attribution event; without one
-        the event is a bare lane entry."""
+        the thread that ran the phase."""
         ev = {
             "lane": lane, "batch": int(batch), "scope": scope,
             "device": str(device), "stream": int(stream),
@@ -129,64 +81,6 @@ class FlightRecorder:
         TRACE_TIMELINE_EVENTS.inc()
         with self._lock:
             self.ring.put_nowait(ev)
-            if run is None:
-                return
-            key = (scope, int(run), int(batch))
-            if lane in _BOUND_COMPONENTS:
-                entry = self._open.setdefault(key, {})
-                entry[lane] = (t0, t1)
-                if device:
-                    # The batch's device stream (its h2d/kernel phases
-                    # carry it; stage/retire run off-device): the
-                    # window event inherits it so bound attribution
-                    # names WHICH stream was bound, per device lane.
-                    entry["_dev"] = (str(device), int(stream))
-                while len(self._open) > _OPEN_CAP:
-                    # Crashed-run backstop: drop the OLDEST open
-                    # window (dict preserves insertion order) rather
-                    # than grow with abandoned batches.
-                    self._open.pop(next(iter(self._open)))
-            elif lane == "retire":
-                phases = self._open.pop(key, {})
-                phases["retire"] = (t0, t1)
-                win = self._window_event(ev, phases)
-                if win is not None:
-                    TRACE_TIMELINE_EVENTS.inc()
-                    self.ring.put_nowait(win)
-
-    @staticmethod
-    def _window_event(retire_ev: Dict[str, Any],
-                      phases: Dict[str, Tuple[float, float]]
-                      ) -> Optional[Dict[str, Any]]:
-        """Bound attribution for one retired batch: which of
-        max(t_stage, t_h2d, t_kernel) bound it, and by how much over
-        the runner-up (the margin a perfect pipeline of this shape
-        cannot hide)."""
-        dev, stream = phases.pop("_dev", (retire_ev["device"],
-                                          retire_ev["stream"]))
-        durs = {lane: t1 - t0 for lane, (t0, t1) in phases.items()}
-        comps = [(durs.get(lane, 0.0), lane)
-                 for lane in _BOUND_COMPONENTS]
-        comps.sort(reverse=True)
-        (best, binding), (second, _) = comps[0], comps[1]
-        if best <= 0.0:
-            return None  # phases never recorded (partial run)
-        t0 = min(t0 for t0, _ in phases.values())
-        t1 = max(t1 for _, t1 in phases.values())
-        win = {
-            "lane": "window", "batch": retire_ev["batch"],
-            "scope": retire_ev["scope"], "device": dev,
-            "stream": stream,
-            "ts_us": tracing.perf_to_us(t0),
-            "dur_us": max(0, int((t1 - t0) * 1e6)),
-            "binding": binding,
-            "margin_us": max(0, int((best - second) * 1e6)),
-            "phases_us": {lane: int(d * 1e6)
-                          for lane, d in sorted(durs.items())},
-        }
-        if "trace" in retire_ev:
-            win["trace"] = retire_ev["trace"]
-        return win
 
     def snapshot(self) -> List[Dict[str, Any]]:
         """Oldest-first copy of the ring (JSON-safe; what
@@ -195,18 +89,17 @@ class FlightRecorder:
             return [dict(ev) for ev in self.ring]
 
     def clear(self) -> None:
-        """Test/bench hook: empty the ring and drop open windows."""
+        """Test/bench hook: empty the ring."""
         with self._lock:
             while True:
                 try:
                     self.ring.get_nowait()
                 except Exception:
                     break
-            self._open.clear()
 
 
-# THE process-wide recorder (the pipeline writes here; multiple
-# concurrent runs interleave by design — events carry their trace id).
+# THE process-wide recorder (the identify planes write here; concurrent
+# jobs interleave by design — events carry their trace id).
 RECORDER = FlightRecorder()
 
 
@@ -227,8 +120,8 @@ PID_TIMELINE = 2
 
 def _timeline_tid_name(ev: Dict[str, Any]) -> str:
     """Lane naming: per-device h2d/kernel streams, per-worker stage
-    lanes, one retire lane, one window (bound-attribution) lane — the
-    'per-device stage/H2D/kernel/retire lanes' the export promises."""
+    lanes, one retire lane — the 'per-device stage/H2D/kernel/retire
+    lanes' the export promises."""
     lane = ev.get("lane", "?")
     dev = ev.get("device", "")
     scope = ev.get("scope", "pipeline")
@@ -296,10 +189,7 @@ def _node_trace_events(spans: List[Dict[str, Any]],
             meta.append({"ph": "M", "name": "thread_name",
                          "pid": pid_timeline, "tid": tid, "ts": 0,
                          "args": {"name": lane_name}})
-        if ev.get("lane") == "window":
-            name = f"bound:{ev.get('binding', '?')}"
-        else:
-            name = f"{ev.get('lane', '?')} b{ev.get('batch', '?')}"
+        name = f"{ev.get('lane', '?')} b{ev.get('batch', '?')}"
         args = {k: v for k, v in ev.items() if k != "ts_us"}
         events.append({
             "ph": "X", "name": name,
@@ -392,7 +282,7 @@ def write_trace_artifact(path: str, node_name: str) -> List[str]:
     trace, validate, and write it ONLY when schema-clean. Returns the
     problem list (empty = written) — the caller decides how to fail.
     One implementation so the export/validate/write sequence cannot
-    drift between overlap_bench, perf_smoke, and future tools."""
+    drift between perf_smoke and future tools."""
     import json
 
     from . import persist

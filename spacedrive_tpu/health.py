@@ -21,10 +21,8 @@ observatory closes both gaps:
   the declared registries — channel depth/high-water vs declared
   capacity plus shed rate (channels.py), timeout firing rates
   (timeouts.py), store write-lock wait and commit latency, the task
-  census vs the supervisor's ownership tree (tasks.py), the pipeline
-  stage/retire stall split plus the flight recorder's per-batch bound
-  attribution (`sd_pipeline_*`, flight.py), and the sanitizer/race
-  violation counters — and emits a per-subsystem state
+  census vs the supervisor's ownership tree (tasks.py), and the
+  sanitizer/race violation counters — and emits a per-subsystem state
   (``ok | degraded | saturated``) with **bottleneck attribution**: the
   top-k resources driving the state, named by their declared registry
   name/owner/doc, with the evidence series inline.
@@ -83,10 +81,6 @@ GROUP_WAIT_SATURATED_S = 2.0
 # Declared network budgets firing: any firing degrades; a sustained
 # rate saturates (the peer/path is effectively down).
 TIMEOUT_SATURATED_PER_S = 0.5
-# Pipeline stall seconds accumulated per wall second (a dispatcher or
-# retirer parked more than this fraction of the window).
-PIPELINE_STALL_DEGRADED = 0.2
-PIPELINE_STALL_SATURATED = 0.6
 # Ring tail included per attribution entry ("evidence series inline").
 EVIDENCE_POINTS = 32
 
@@ -276,7 +270,7 @@ class HealthMonitor:
                                 "health.series")
                         ring.put_nowait([round(wall, 3), _round(point)])
 
-            findings = _evaluate(window, dt, wall)
+            findings = _evaluate(window, dt)
             census: Dict[str, int] = {}
             for r in tasks.live():
                 root = tasks.owner_label(r.owner).split("/")[0]
@@ -362,8 +356,8 @@ def _by_family(window: Dict[str, Dict], family: str
             if rec["family"] == family]
 
 
-def _evaluate(window: Dict[str, Dict], dt: Optional[float],
-              wall: float) -> List[Dict[str, Any]]:
+def _evaluate(window: Dict[str, Dict],
+              dt: Optional[float]) -> List[Dict[str, Any]]:
     """Cross-read the declared registries over the freshest window and
     name what is saturated and what it is blocked on. Rates need a
     window: the very first sample (dt None) judges gauges/depths
@@ -373,7 +367,6 @@ def _evaluate(window: Dict[str, Dict], dt: Optional[float],
     finds.extend(_timeout_findings(window, dt))
     finds.extend(_store_findings(window))
     finds.extend(_task_findings(window, dt))
-    finds.extend(_pipeline_findings(window, dt, wall))
     finds.extend(_sanitize_findings(window, dt))
     finds.extend(_incident_findings(window, dt))
     return finds
@@ -563,61 +556,6 @@ def _task_findings(window, dt) -> List[Dict[str, Any]]:
             evidence={_series_key("sd_sanitize_violations_total",
                                   {"kind": "task_exception"}): exc}))
     return finds
-
-
-def _pipeline_findings(window, dt, wall) -> List[Dict[str, Any]]:
-    if dt is None:
-        return []
-    stage_r = (_win(window, "sd_pipeline_stage_stall_seconds_total")
-               or {}).get("rate") or 0.0
-    retire_r = (_win(window, "sd_pipeline_retire_stall_seconds_total")
-                or {}).get("rate") or 0.0
-    h2d_r = (_win(window, "sd_pipeline_h2d_seconds_total")
-             or {}).get("rate") or 0.0
-    busy = max(stage_r, retire_r)
-    if busy < PIPELINE_STALL_DEGRADED:
-        return []
-    sev = 2 if busy >= PIPELINE_STALL_SATURATED else 1
-    evidence = {
-        "sd_pipeline_stage_stall_seconds_total": stage_r,
-        "sd_pipeline_retire_stall_seconds_total": retire_r,
-        "sd_pipeline_h2d_seconds_total": h2d_r,
-    }
-    if stage_r >= retire_r:
-        resource = "ops.pipeline.stage"
-        reason = (f"dispatchers starved {stage_r:.2f} stall-s/s "
-                  "waiting on staged batches — the pipeline is "
-                  "stage-bound")
-        doc = _family_doc("sd_pipeline_stage_stall_seconds_total")
-    else:
-        binding = _flight_binding(wall, dt) or (
-            "h2d" if h2d_r >= 0.5 * retire_r else "kernel")
-        resource = f"ops.pipeline.{binding}"
-        reason = (f"retirer starved {retire_r:.2f} stall-s/s; recent "
-                  f"batch windows attribute the bound to {binding}")
-        doc = _family_doc("sd_pipeline_h2d_seconds_total") \
-            if binding == "h2d" else \
-            _family_doc("sd_pipeline_retire_stall_seconds_total")
-    return [_finding(resource, "ops", sev, busy, reason,
-                     owner="ops", doc=doc, evidence=evidence)]
-
-
-def _flight_binding(wall: float, dt: float) -> Optional[str]:
-    """The dominant bound (stage|h2d|kernel) named by the flight
-    recorder's per-batch window events inside the sampling window —
-    the forensic half of the pipeline attribution."""
-    from . import flight
-
-    t0_us = int((wall - dt) * 1e6)
-    counts: Dict[str, int] = {}
-    for ev in flight.RECORDER.snapshot():
-        if ev.get("lane") == "window" and ev.get("ts_us", 0) >= t0_us:
-            b = ev.get("binding")
-            if b:
-                counts[b] = counts.get(b, 0) + 1
-    if not counts:
-        return None
-    return max(counts.items(), key=lambda kv: (kv[1], kv[0]))[0]
 
 
 def _sanitize_findings(window, dt) -> List[Dict[str, Any]]:
@@ -818,12 +756,6 @@ READS: Dict[str, str] = {
         "per-statement row throughput (hottest-statement attribution)",
     "sd_task_spawned_total": "supervisor spawn rate (census context)",
     "sd_task_orphaned_total": "tasks surviving the shutdown reap",
-    "sd_pipeline_stage_stall_seconds_total":
-        "identify-pipeline dispatcher starvation (stage-bound)",
-    "sd_pipeline_retire_stall_seconds_total":
-        "identify-pipeline retirer starvation (device-bound)",
-    "sd_pipeline_h2d_seconds_total":
-        "host→device transfer occupancy of the pipeline",
     "sd_sanitize_violations_total":
         "runtime-sanitizer detections by kind",
     "sd_race_candidates_total":

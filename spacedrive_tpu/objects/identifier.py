@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..sync.crdt import OpKind, uuid4_bytes_batch
 
+from .. import flags
 from ..files import resolve_kind
 from ..jobs.job import EarlyFinish, JobContext, StatefulJob, StepOutcome, register_job
 from ..locations.file_path_helper import materialized_like, sub_path_children_mat
@@ -51,6 +52,24 @@ def _usable_cpus() -> int:
         return len(os.sched_getaffinity(0))
     except (AttributeError, OSError):
         return os.cpu_count() or 1
+
+
+def _hash_ahead(device_engaged: bool) -> bool:
+    """Whether the identifier stages and hashes chunk k+1 in a worker
+    thread while chunk k's transaction commits.
+
+    It needs a second USABLE core (affinity/cgroup-aware, not
+    cpu_count): measured on a 1-core host it LOSES ~8% (WAL +
+    synchronous=NORMAL commits don't fsync, so there is no IO wait to
+    hide under — only GIL contention). On device-engaged runs it also
+    needs buffer donation (SDTPU_DONATE_BUFFERS): donation is what makes
+    a second in-flight device batch safe — the worker's stage+H2D+hash
+    of chunk k+1 then no longer pins a second batch's device inputs
+    against chunk k's in-flight dispatch, so the device stream stays fed
+    through the whole db+commit phase. With donation off, device runs
+    keep the serial shape."""
+    return _usable_cpus() > 1 and (
+        not device_engaged or bool(flags.get("SDTPU_DONATE_BUFFERS")))
 
 
 def orphan_filters(location_id: int, cursor: int,
@@ -386,29 +405,14 @@ class FileIdentifierJob(StatefulJob):
         # Two configurations keep one chunk per step: device-engaged
         # runs (their chunks are already 8-16k files, and a second
         # device dispatch must never run under the held write lock) and
-        # hash-ahead hosts (≥2 usable cores — there the worker hash of
+        # hash-ahead runs (see _hash_ahead) — there the worker hash of
         # chunk k+1 overlapping chunk k's whole db+commit phase is
         # worth more than amortized commits, and batching would
-        # serialize the worker behind the group). Commit batching
+        # serialize the worker behind the group. Commit batching
         # targets the remaining case: the single-core host plane, where
         # nothing overlaps anyway and per-chunk commits were pure
         # overhead.
-        # Hash-ahead now covers device-engaged runs too, gated on the
-        # depth-N pipeline being enabled (SDTPU_PIPELINE_DEPTH > 1)
-        # AND buffer donation being on: donation is the actual safety
-        # condition — the worker's stage+H2D+hash of chunk k+1 then no
-        # longer pins a second batch's device inputs against chunk k's
-        # in-flight dispatch (the hazard that once forced device runs
-        # to serialize), so the device stream stays
-        # fed through the whole db+commit phase. Depth 1 or
-        # SDTPU_DONATE_BUFFERS=off restores the serial shape.
-        from .. import flags as _flags
-        from ..ops import overlap as _overlap
-        pipe_depth = _overlap.pipeline_depth()
-        hash_ahead = _usable_cpus() > 1 and (
-            not device_engaged
-            or (pipe_depth > 1
-                and bool(_flags.get("SDTPU_DONATE_BUFFERS"))))
+        hash_ahead = _hash_ahead(device_engaged)
         commit_every = (1 if device_engaged or hash_ahead
                         else max(1, min(8, 16384 // chunk)))
         data = {
@@ -420,20 +424,8 @@ class FileIdentifierJob(StatefulJob):
             # replays use the same pagination the steps were counted for.
             "chunk_size": chunk,
             "commit_every": commit_every,
-            # Recorded for the artifact trail (bench/perf_smoke report
-            # it): which pipeline depth the device stream ran under.
-            "pipeline_depth": pipe_depth if device_engaged else None,
-            # Hash-ahead (stage+hash chunk i+1 in a worker thread while
-            # chunk i's transaction commits) runs on the host planes
-            # and, since the depth-N ring landed, on device-engaged
-            # runs whenever SDTPU_PIPELINE_DEPTH > 1 AND
-            # SDTPU_DONATE_BUFFERS is on (donated buffers are what
-            # make a second in-flight device batch safe — see the
-            # commit_every note above). It still needs a second USABLE
-            # core (affinity/cgroup-aware, not cpu_count): measured on
-            # a 1-core host it LOSES ~8% (WAL+synchronous=NORMAL
-            # commits don't fsync, so there is no IO wait to hide
-            # under — only GIL contention).
+            # Stage+hash chunk i+1 in a worker thread while chunk i's
+            # transaction commits (_hash_ahead says when).
             "hash_ahead": hash_ahead,
             "cursor": 0,
             "linked": 0, "created": 0, "skipped": 0, "total_orphans": count,
@@ -741,6 +733,4 @@ class FileIdentifierJob(StatefulJob):
             metadata["phase_ms"] = {
                 k: round(v * 1000.0, 1) for k, v in sorted(phase.items())}
             metadata["chunk_size"] = data.get("chunk_size")
-            if data.get("pipeline_depth") is not None:
-                metadata["pipeline_depth"] = data["pipeline_depth"]
         return metadata

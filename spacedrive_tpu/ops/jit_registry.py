@@ -476,26 +476,6 @@ declare_jit(
                   "batch whole decode sets); bit fetch declared")
 
 declare_jit(
-    "overlap.kernel", "spacedrive_tpu/ops/overlap.py::_jitted",
-    kind="factory", max_traces=64,
-    in_dtypes=("uint32", "int32"), out_dtypes=("uint32",),
-    donate_argnums=(0, 1),
-    shape_buckets="lru-cached jit per (kernel fn, donate) pair (the "
-                  "round-10 fix for the per-call jax.jit(fn) "
-                  "recompile); one large-class batch grid per run, "
-                  "times the round-robin device count (committed "
-                  "inputs compile one program per device). The "
-                  "donated variant consumes its (words, lengths) "
-                  "inputs — the depth-N ring's recycled H2D buffers")
-
-declare_jit(
-    "overlap.retire", "spacedrive_tpu/ops/overlap.py::run_overlapped",
-    kind="wrapper", host_transfer=True,
-    out_dtypes=("uint32",),
-    shape_buckets="digest retirement + calibration sync markers are "
-                  "the pipeline's declared D2H points")
-
-declare_jit(
     "staging.h2d_probe", "spacedrive_tpu/ops/staging.py::h2d_gbps",
     kind="wrapper", host_transfer=True,
     shape_buckets="one 8 MiB probe buffer, once per process (disk "

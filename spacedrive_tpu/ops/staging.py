@@ -50,7 +50,7 @@ from ..telemetry import (
 
 # Monotone hashing-chunk ordinal for the flight recorder's "identify"
 # scope: host-plane chunks get timeline lanes too, so the export shows
-# the hash-ahead cadence next to the device pipeline's per-batch ring.
+# the hash-ahead cadence next to the jax backend's per-chunk lanes.
 _CHUNK_SEQ = itertools.count(1).__next__
 
 _log = logging.getLogger(__name__)
@@ -86,12 +86,6 @@ def _pool() -> concurrent.futures.ThreadPoolExecutor:
                 _ATEXIT_REGISTERED = True
                 atexit.register(shutdown_stage_pool)
         return _STAGE_POOL
-
-
-def stage_pool() -> concurrent.futures.ThreadPoolExecutor:
-    """Public spelling of the shared staging executor (the depth-N
-    overlap pipeline submits its concurrent stage(i+1..i+k) here)."""
-    return _pool()
 
 
 def _submit(fn, *args) -> concurrent.futures.Future:
@@ -231,8 +225,7 @@ def stage_files(
     # SDTPU_STAGE_NATIVE=off is the WHOLE native-staging escape hatch:
     # it pins not just the packed path (stage_batch_native) but these
     # classic native reads too, so "off" really means the pure-Python
-    # readers — the baseline tools/overlap_bench.py --staging python
-    # measures against.
+    # readers.
     mode = str(flags.get("SDTPU_STAGE_NATIVE") or "auto")
     if mode not in ("off", "0", "no", "false") and _native.available():
         return _stage_files_native(files, large_idx, small_idx, empty_idx)
@@ -264,12 +257,12 @@ def stage_files(
            [("small", row, idx)
             for row, idx in enumerate(small_idx)]
     if threading.current_thread().name.startswith("cas-stage"):
-        # Already ON a stage-pool worker (the depth-N pipeline stages
-        # whole batches through the same executor): submitting the
-        # per-file reads back into the pool and blocking on them can
-        # starve — depth >= workers pins every worker on a batch whose
-        # inner reads never get a thread. Nested staging reads inline;
-        # batches still parallelize across the outer workers.
+        # Already ON a stage-pool worker (the identifier's hash-ahead
+        # stages whole chunks through the same executor): submitting
+        # the per-file reads back into the pool and blocking on them
+        # can starve — outer jobs >= workers pins every worker on a
+        # batch whose inner reads never get a thread. Nested staging
+        # reads inline.
         for job in jobs:
             read_one(*job)
     else:
@@ -288,7 +281,7 @@ def stage_files(
     return large_batch, small_batch, empty_idx, errors
 
 
-# -- native packed staging (zero-copy ring feed) ---------------------------
+# -- native packed staging (zero-copy H2D feed) ----------------------------
 #
 # The classic path above stages per-class payload matrices and then
 # pays a full build_cas_messages pass — allocate a fresh [B, C*1024]
@@ -322,7 +315,7 @@ class StageLease:
 
 
 class StagePool:
-    """Bounded pool of page-aligned staging pages (the donation ring's
+    """Bounded pool of page-aligned staging pages (the jax backend's
     H2D sources). Anonymous mmap allocations are page-aligned by
     construction; a free page is reused for any batch whose rows fit
     its capacity. Checkouts are metered through the declared
@@ -391,7 +384,7 @@ _STAGE_BUF_POOL: Optional[StagePool] = None
 
 def stage_buffer_pool() -> StagePool:
     """The process-wide staging page pool (one declared window meters
-    every ring)."""
+    every checkout)."""
     global _STAGE_BUF_POOL
     with _BUF_POOL_LOCK:
         if _STAGE_BUF_POOL is None:
@@ -697,7 +690,7 @@ def _h2d_probe_key() -> Optional[str]:
     """Cache key binding a probe result to the device set it measured:
     backend platform + device count. The on-disk cache outlives the
     process — without the key, a stale CPU-backend probe (a laptop
-    run, a tier-1 test) would mis-calibrate the ring on a bench host
+    run, a tier-1 test) would mis-calibrate the policy on a bench host
     for up to the TTL. None (jax unavailable) disables the disk cache
     rather than trusting an unkeyed entry."""
     try:
@@ -910,9 +903,9 @@ def cas_ids_for_files(
             large, small, empty_idx, errors = stage_files(files)
             t1 = time.perf_counter()
             ids = dict(_BACKENDS[backend](files, large, small))
-            # Host-plane chunks get the same stage/kernel lanes as the
-            # depth-N pipeline (scope "identify"): the exporter shows
-            # hash-ahead chunk cadence next to the device ring's lanes.
+            # Host-plane chunks get stage/kernel lanes (scope
+            # "identify"): the exporter shows the hash-ahead chunk
+            # cadence.
             RECORDER.record("stage", batch=chunk, t0=t0, t1=t1,
                             device=backend, scope="identify", trace=trace,
                             files=len(files))

@@ -64,21 +64,5 @@ def tile_mesh(devices=None) -> Mesh:
     return _tile_mesh_cached(devices)
 
 
-def device_ring(limit: int = 0, devices=None) -> tuple:
-    """The local devices the depth-N identify pipeline round-robins
-    in-flight batches across (ops/overlap.py): the batch_mesh device
-    tuple, optionally capped at `limit` (> 0).
-
-    Returning the SAME tuple the cached batch mesh is built from keeps
-    one code path covering 1→8 chips: single-chip hosts get a ring of
-    one, pod slices get per-device staging streams, and the mesh-cached
-    sharded kernels (blake3 sharded, seqhash reduce) see an identical
-    device ordering when a caller composes both."""
-    devices = tuple(jax.devices()) if devices is None else tuple(devices)
-    if limit and limit > 0:
-        devices = devices[:limit]
-    return devices or tuple(jax.devices())[:1]
-
-
 def pad_to_multiple(n: int, m: int) -> int:
     return -(-n // m) * m

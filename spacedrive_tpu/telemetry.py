@@ -595,38 +595,6 @@ VALIDATOR_FILES = counter(
 VAL_BATCHED_FILES = VALIDATOR_FILES.labels(path="batched")
 VAL_STREAMED_FILES = VALIDATOR_FILES.labels(path="streamed")
 
-# -- pipeline (ops/overlap.py depth-N identify pipeline) --------------------
-PIPELINE_DEPTH_HIGH_WATER = gauge(
-    "sd_pipeline_depth_high_water",
-    "Most batches simultaneously in flight (stage→H2D→kernel→fetch) "
-    "observed in the depth-N identify pipeline since process start "
-    "(≤ SDTPU_PIPELINE_DEPTH by construction)")
-PIPELINE_STAGE_STALL_SECONDS = counter(
-    "sd_pipeline_stage_stall_seconds_total",
-    "Dispatcher time spent waiting on the staged-batch channel — the "
-    "un-hidden remainder when staging is the pipeline bottleneck")
-PIPELINE_RETIRE_STALL_SECONDS = counter(
-    "sd_pipeline_retire_stall_seconds_total",
-    "Retirer time spent waiting on the in-flight window — pipeline "
-    "starvation (H2D/kernel slower than the fetch side)")
-PIPELINE_H2D_BYTES = counter(
-    "sd_pipeline_h2d_bytes_total",
-    "Host→device bytes transferred by the pipeline dispatchers "
-    "(simulated-link runs count the simulated bytes too)")
-PIPELINE_H2D_SECONDS = counter(
-    "sd_pipeline_h2d_seconds_total",
-    "Wall seconds the pipeline dispatchers spent in host→device "
-    "transfer (including the SDTPU_SIM_LINK_GBPS injected delay)")
-PIPELINE_DONATED_REUSE = counter(
-    "sd_pipeline_donated_reuse_total",
-    "Staged device buffers consumed by donated kernel dispatches "
-    "(each is allocator space recycled for a later batch's H2D "
-    "instead of pinned until digest retirement)")
-PIPELINE_DEVICE_BATCHES = counter(
-    "sd_pipeline_device_batches_total",
-    "Batches dispatched per local device by the round-robin pipeline",
-    labelnames=("device",))
-
 # -- stage pool (ops/staging.py shared staging executor) --------------------
 STAGE_POOL_WORKERS = gauge(
     "sd_stage_pool_workers",
@@ -664,7 +632,7 @@ STAGE_POOL_BUFFERS = gauge(
 STAGE_POOL_HIGH_WATER = gauge(
     "sd_stage_pool_high_water",
     "Peak concurrent StagePool checkouts since process start — how "
-    "close the ring came to the declared pool bound")
+    "close the identify path came to the declared pool bound")
 
 # -- sync (sync/manager.py, sync/ingest.py, sync/opblob.py) -----------------
 SYNC_OPS_ENCODED = counter(

@@ -3,15 +3,15 @@ channels.py's capacity table and timeouts.py's budget table.
 
 Every class whose methods are reachable from more than one THREAD
 CONTEXT (the event loop, `asyncio.to_thread`/executor submit targets,
-the ops/staging.py pool workers, the per-device dispatch streams in
-ops/overlap.py, atexit/signal shutdown hooks) DECLARES a contract per
-mutable attribute here — which thread(s) may write it and under which
-lock. The depth-N pipeline review (PR 8) burned both rounds hand-fixing
-exactly this bug class: `PipelineStats` plain `+=` lost updates at more
-than one device stream, and the stage-pool gauge clobbered across a
-concurrent pool swap. With the contracts machine-readable, tools/sdlint
-checks them statically (shared-mutation / thread-boundary /
-guard-consistency passes) and this module checks them dynamically (the
+the ops/staging.py pool workers, atexit/signal shutdown hooks)
+DECLARES a contract per mutable attribute here — which thread(s) may
+write it and under which lock. An earlier multi-stream identify
+pipeline burned two review rounds hand-fixing exactly this bug class: a
+stats object's plain `+=` lost updates at more than one device stream,
+and the stage-pool gauge clobbered across a concurrent pool swap.
+With the contracts machine-readable, tools/sdlint checks them
+statically (shared-mutation / thread-boundary / guard-consistency
+passes) and this module checks them dynamically (the
 `__setattr__`/container write recorder armed by `sanitize.install()`).
 
 Contract kinds (one per attribute):
@@ -672,13 +672,11 @@ declare_owner(
     "flight.FlightRecorder", "spacedrive_tpu/flight.py::FlightRecorder",
     {
         "ring": immutable_after_init(),
-        "_open": guarded_by("_lock"),
     },
-    "Flight-recorder timeline ring: the per-device dispatch executor "
-    "threads, the retire thread, and the pipeline coroutines all "
-    "record phases — every ring put and open-window mutation runs "
-    "under the recorder's _lock; the ring channel itself is bound at "
-    "construction and never rebound.")
+    "Flight-recorder timeline ring: the identifier's hashing threads "
+    "and its hash-ahead worker all record phases — every ring put "
+    "runs under the recorder's _lock; the ring channel itself is "
+    "bound at construction and never rebound.")
 
 declare_owner(
     "health.HealthMonitor", "spacedrive_tpu/health.py::HealthMonitor",
@@ -711,39 +709,6 @@ declare_owner(
     "windows, store accounting, and degraded-streak map all move "
     "under the observatory's _lock leaf (health samples arrive from "
     "whichever thread asked the monitor to sample).")
-
-declare_owner(
-    "overlap.PipelineStats",
-    "spacedrive_tpu/ops/overlap.py::PipelineStats",
-    {
-        "h2d_bytes": guarded_by("_lock"),
-        "h2d_s": guarded_by("_lock"),
-        "donated_reuse": guarded_by("_lock"),
-        "buffer_samples": guarded_by("_lock"),
-        "stage_s": guarded_by("_lock"),
-        "retire_stall_s": guarded_by("_lock"),
-        "calibration_s": guarded_by("_lock"),
-        "samples": guarded_by("_lock"),
-        "depth_high_water": guarded_by("_lock"),
-        "per_device_batches": guarded_by("_lock"),
-        "stage_native_batches": guarded_by("_lock"),
-        "stage_python_batches": guarded_by("_lock"),
-        "files": single_thread(),
-        "wall_s": single_thread(),
-        "batches": single_thread(),
-        "batch_files": single_thread(),
-        "t_stage_1": single_thread(),
-        "t_h2d_1": single_thread(),
-        "t_kernel_1": single_thread(),
-        "t_stage_2": single_thread(),
-        "t_h2d_2": single_thread(),
-        "t_kernel_2": single_thread(),
-    },
-    "Depth-N pipeline stats: the per-device executor streams AND the "
-    "pipeline coroutines mutate the accounting fields (the PR 8 "
-    "lost-update class), so everything multi-writer sits under _lock; "
-    "the run-shape and bracket fields are finalized by the one thread "
-    "driving run_overlapped.")
 
 declare_owner(
     "store.Database", "spacedrive_tpu/store/db.py::Database",

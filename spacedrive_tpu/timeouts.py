@@ -434,22 +434,6 @@ declare_timeout(
     "(fleet.py assemble_trace): span-ring + timeline copies are "
     "bigger than health snapshots, so the budget is too.")
 
-# -- ops (device-pipeline put budgets; not wire awaits) ---------------------
-
-declare_timeout(
-    "ops.pipeline.inflight.put", 600.0,
-    "Depth-N identify pipeline dispatcher waiting for the retirer to "
-    "drain the in-flight window (channels.py ops.pipeline.inflight): "
-    "a wedged D2H fetch frees the dispatcher here instead of parking "
-    "the device stream forever. Sized for slow-link H2D at bench "
-    "batch sizes.")
-
-declare_timeout(
-    "ops.pipeline.staged.put", 600.0,
-    "Depth-N identify pipeline stager waiting for a dispatcher to "
-    "drain the staged-batch channel (channels.py ops.pipeline.staged) "
-    "— the backpressure edge when H2D or the kernel is the bottleneck.")
-
 # -- p2p (tunnel control plane) ---------------------------------------------
 
 declare_timeout(

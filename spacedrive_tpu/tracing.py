@@ -571,12 +571,6 @@ declare_span(
     "field.")
 
 declare_span(
-    "pipeline.run",
-    "One depth-N identify pipeline run (ops/overlap.run_overlapped); "
-    "the flight recorder's timeline events carry this span's trace "
-    "id.")
-
-declare_span(
     "rpc",
     "One rspc query/mutation dispatch on the API host (api/"
     "server.py); the variant is the procedure path. Continues the "

@@ -47,7 +47,7 @@ def enable_compile_cache() -> str:
     A ``JAX_COMPILATION_CACHE_DIR`` from the environment is used as is
     (JAX reads it itself) and nothing else is set. Otherwise the cache
     lives at the fixed ``DEFAULT_COMPILE_CACHE``. Entry points call
-    this (chip_smoke.py, bench.py, ``serve``, tools/perf_smoke.py);
+    this (chip_smoke.py, ``serve``, tools/perf_smoke.py);
     importing the package does not, so the tests stay uncached.
     """
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

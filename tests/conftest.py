@@ -24,15 +24,12 @@ ensure_host_device_count(8)
 # this back) plus the driver's dryrun_multichip stage 6.
 os.environ.setdefault("SDTPU_SHARDED_CAS", "off")
 
-# Same compile-cost hygiene for the depth-N overlap pipeline: donated
-# kernel twins and per-device programs each cost a fresh ~45 s BLAKE3
-# compile on CPU for zero extra coverage of the REAL kernel (identity
-# pass-through donation cannot change digests). The suite pins the
-# undonated single-device programs; the dedicated donation/multi-device
-# tests in test_overlap.py flip these back over cheap kernels, and
-# test_blake3_jax pins the donated CAS dispatch plumbing.
+# Same compile-cost hygiene for the donated CAS dispatch: its donated
+# kernel twin costs a fresh ~45 s BLAKE3 compile on CPU for zero extra
+# coverage of the REAL kernel (donation cannot change digests). The
+# suite pins the undonated program; test_blake3_jax pins the donated
+# dispatch plumbing.
 os.environ.setdefault("SDTPU_DONATE_BUFFERS", "off")
-os.environ.setdefault("SDTPU_PIPELINE_DEVICES", "1")
 
 # Tier-1 runs SANITIZED (spacedrive_tpu/sanitize.py): every asyncio
 # callback is timed (loop-stall detector), the store's locks record

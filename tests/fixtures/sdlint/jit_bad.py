@@ -36,7 +36,7 @@ def positional_static(x, n):
 
 
 def call_time(fn, words, lengths):
-    # the overlap.py:166 shape: a fresh jit wrapper per invocation
+    # the early pipeline's shape: a fresh jit wrapper per invocation
     jfn = jax.jit(fn)
     return jfn(words, lengths)
 

@@ -2,8 +2,8 @@
 
 Every contract kind broken once, plus the two registry-enforcement
 codes. The `h2d_bytes` bare `+=` from a run_in_executor target is the
-encoded PR 8 PipelineStats lost-update shape — the pass must keep
-catching it. The fixture self-declares its contracts (declare_owner is
+encoded lost-update shape of an early multi-stream pipeline's stats —
+the pass must keep catching it. The fixture self-declares its contracts (declare_owner is
 parsed from project files as well as the central registry, exactly so
 fixtures can do this)."""
 
@@ -42,7 +42,7 @@ class RaceStats:
 
 
 def _transfer(stats: RaceStats) -> None:
-    # BAD unguarded-write: the PipelineStats shape — a per-device
+    # BAD unguarded-write: the lost-update stats shape — a per-device
     # executor stream bumping a guarded counter with no lock held.
     stats.h2d_bytes += 57344
 

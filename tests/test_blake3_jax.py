@@ -4,11 +4,13 @@ import os
 import random
 
 import numpy as np
+import pytest
 
 from spacedrive_tpu.ops.blake3_jax import (
     blake3_words,
     build_cas_messages,
     cas_ids_jax,
+    cas_rows,
     digests_to_cas_ids,
     digests_to_hex,
     make_sharded_blake3,
@@ -79,8 +81,7 @@ def test_cas_dispatch_routes_donated_entry(monkeypatch):
     entry — `_donated_local` over the `blake3.donated` contract — and
     the CAS IDs come out unchanged. The stand-in delegates to the
     already-compiled undonated program, keeping this a pure plumbing
-    test; the donated program's real consume-at-dispatch semantics are
-    pinned by test_overlap.py's footprint test over a cheap kernel."""
+    test: the donated program itself is never compiled here."""
     from spacedrive_tpu.ops import blake3_jax as bj
 
     sizes = [0, 77, 4096]
@@ -110,6 +111,20 @@ def test_cas_dispatch_routes_donated_entry(monkeypatch):
     got_off = cas_ids_jax(payloads, np.array(sizes, np.uint64),
                           payload_lens=lens)
     assert not calls and got_off == want
+
+
+@pytest.mark.parametrize("B,n_dev,rows", [
+    (1, 1, 8), (8, 1, 8), (9, 1, 32), (2049, 1, 4096),
+    (9, 4, 32), (2049, 4, 4096),
+])
+def test_cas_rows_bucket_and_mesh_padding(B, n_dev, rows):
+    """The CAS grid's row count, shared by the packed stager (the pooled
+    page it stages into) and the dispatch (the program it runs): B's
+    bucket, past the largest bucket a multiple of it, padded to a
+    multiple of the device count so every shard gets equal rows."""
+    got = cas_rows(B, n_dev)
+    assert got == rows
+    assert got >= B and got % n_dev == 0
 
 
 def test_sharded_blake3_on_cpu_mesh(cpu_devices):

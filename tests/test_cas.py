@@ -169,3 +169,57 @@ def test_identifier_auto_resolves_device_chunk(tmp_path, monkeypatch):
             await node.shutdown()
 
     assert asyncio.run(scenario()) == 8
+
+
+@pytest.mark.parametrize(
+    "device,donate,cpus,hash_ahead,commit_every", [
+        (False, "on", 1, False, 8),
+        (False, "on", 2, True, 1),
+        (True, "on", 2, True, 1),
+        (True, "off", 2, False, 1),
+        (True, "on", 1, False, 1),
+    ], ids=["host-1cpu", "host-2cpu", "device-donate-2cpu",
+            "device-nodonate-2cpu", "device-donate-1cpu"])
+def test_identifier_hash_ahead_rule(tmp_path, monkeypatch, device, donate,
+                                    cpus, hash_ahead, commit_every):
+    """The identifier hashes chunk k+1 ahead of chunk k's commit only
+    with a second usable core, and on device-engaged runs only with
+    buffer donation on. A step commits one chunk unless neither
+    applies: the single-core host plane groups 8 reference-size chunks
+    per commit."""
+    import asyncio
+    from types import SimpleNamespace
+
+    from spacedrive_tpu.locations.indexer_job import IndexerJob
+    from spacedrive_tpu.locations.manager import create_location
+    from spacedrive_tpu.node import Node
+    from spacedrive_tpu.objects import identifier
+
+    corpus = tmp_path / "corpus"
+    corpus.mkdir()
+    for i in range(3):
+        (corpus / f"f{i}.bin").write_bytes(bytes([i]) * 300)
+    monkeypatch.setattr(identifier, "_usable_cpus", lambda: cpus)
+    monkeypatch.setenv("SDTPU_DONATE_BUFFERS", donate)
+    assert identifier._hash_ahead(device) is hash_ahead
+
+    async def scenario():
+        node = Node(str(tmp_path / "data"))
+        await node.start()
+        try:
+            lib = node.create_library("lib")
+            loc = create_location(lib, str(corpus))
+            await node.jobs.wait(await node.jobs.ingest(
+                lib, IndexerJob(location_id=loc)))
+            job = identifier.FileIdentifierJob(
+                location_id=loc, backend="jax" if device else "numpy")
+            ctx = SimpleNamespace(db=lib.db, progress=lambda **kw: None)
+            return await job.init(ctx)
+        finally:
+            await node.shutdown()
+
+    data, steps = asyncio.run(scenario())
+    assert data["hash_ahead"] is hash_ahead
+    assert data["commit_every"] == commit_every
+    assert data["chunk_size"] == identifier.CHUNK_SIZE
+    assert len(steps) == 1

@@ -302,46 +302,6 @@ def test_scenario_held_write_lock_attributed_to_store(tmp_path):
     db.close()
 
 
-def test_scenario_simlink_pipeline_attributed_to_h2d(tmp_path,
-                                                     monkeypatch):
-    """A sim-link-throttled depth-N run: H2D dominates every batch
-    window, the retirer starves — the ops subsystem degrades with the
-    bound attributed to ops.pipeline.h2d (cross-read from the flight
-    recorder's per-batch bound attribution)."""
-    from spacedrive_tpu.ops import overlap
-    from tools.overlap_bench import _cheap_kernel
-
-    # Warm the cheap kernel at the measured batch shape OUTSIDE the
-    # window so a cold jit compile cannot dilute the stall rates.
-    warm_dir = tmp_path / "warm"
-    warm_dir.mkdir()
-    warm = overlap.make_sparse_corpus(str(warm_dir), 512, 120_000, 512)
-    overlap.run_overlapped(warm, kernel=_cheap_kernel, depth=1,
-                           calibrate_every=8)
-
-    # B=512 @ 0.125 GB/s: ~490 ms of simulated H2D per batch, an
-    # order of magnitude over this container's staging cost — the
-    # same corpus shape the PR 13 sim-link gate pins (a 32-file batch
-    # is genuinely STAGE-bound here, which is correct attribution but
-    # the wrong scenario).
-    monkeypatch.setenv("SDTPU_SIM_LINK_GBPS", "0.125")
-    corpus_dir = tmp_path / "corpus"
-    corpus_dir.mkdir()
-    corpus = overlap.make_sparse_corpus(
-        str(corpus_dir), 512 * 4, 120_000, 512)
-    mon = health.HealthMonitor(interval_s=0.05)
-    _res, _stats = overlap.run_overlapped(
-        corpus, kernel=_cheap_kernel, depth=3,
-        calibrate_every=len(corpus))
-    snap = mon.sample()  # one sampling interval after the run
-    assert snap["states"]["ops"] in ("degraded", "saturated"), \
-        snap["states"]
-    top = snap["attribution"]["ops"][0]
-    assert top["resource"] == "ops.pipeline.h2d", top
-    assert "sd_pipeline_retire_stall_seconds_total" in top["evidence"]
-    assert health.validate_health_snapshot(snap) == []
-
-
 # -- surfaces ----------------------------------------------------------------
 
 def test_node_health_query_and_subscription(tmp_path):
@@ -462,10 +422,10 @@ def test_render_top_frame():
         assert sub in frame
 
 
-def test_overlap_bench_health_flow():
-    """The bench embedding flow (cursors before the sweep, one sample
+def test_bench_health_section_flow():
+    """The bench embedding flow (cursors before the run, one sample
     after) produces a schema-clean health section — the shape
-    overlap_bench --json and perf_smoke --telemetry ship."""
+    perf_smoke --telemetry ships."""
     mon = health.HealthMonitor(interval_s=0.05)
     time.sleep(0.02)
     snap = mon.sample()

@@ -133,8 +133,10 @@ def test_bench_trend_gate_and_readme_sync():
     )
 
     rounds = load_rounds(ROOT)
-    # r01-r05 (remote-chip rounds) were removed in PR 21; r06-r10 stay.
-    assert len(rounds) >= 5
+    # The fleet and load-storm rounds r07-r09 stay; the remote-chip
+    # kernel rounds and the identify-ring rounds left with their
+    # harnesses.
+    assert len(rounds) >= 3
     rows = [normalize(n, doc) for n, doc in rounds]
     assert [p for r in rows for p in r["problems"]] == []
     with open(os.path.join(ROOT, "README.md"), encoding="utf-8") as f:

@@ -269,35 +269,33 @@ def test_pool_recycles_pages_and_scrubs_residue(tmp_path, monkeypatch):
 @requires_native
 def test_overlap_pipeline_digest_parity_and_pool_drain(tmp_path,
                                                        monkeypatch):
-    """End to end through the depth-N ring: native and Python staging
-    produce identical digests for the same corpus, the run reports its
-    backend, and every pooled page is back on the free list when the
-    pipeline drains (recycling is keyed to batch retirement)."""
-    from tools.overlap_bench import _cheap_kernel
-
-    from spacedrive_tpu.ops import overlap
-
-    root = str(tmp_path / "corpus")
-    batches = overlap.make_sparse_corpus(root, 12, 120000, 4)
-    pool = staging.stage_buffer_pool()
-
+    """Chunk after chunk through the jax backend's packed path, as the
+    identifier steps: every CAS ID equals the oracle's over the same
+    payload bytes, every size class stages natively, and every pooled
+    page is back on the free list once its chunk's digests are
+    fetched."""
     monkeypatch.setenv("SDTPU_STAGE_NATIVE", "on")
-    r_native, s_native = overlap.run_overlapped(
-        batches, kernel=_cheap_kernel, depth=3, calibrate_every=99)
-    monkeypatch.setenv("SDTPU_STAGE_NATIVE", "off")
-    r_python, s_python = overlap.run_overlapped(
-        batches, kernel=_cheap_kernel, depth=3, calibrate_every=99)
-
-    assert s_native.staging_backend == "native"
-    assert s_native.stage_native_batches > 0
-    assert s_python.staging_backend == "python"
-    assert s_python.stage_native_batches == 0
-    for a, b in zip(r_native, r_python):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    # retirement returned every lease: nothing checked out
+    pool = staging.stage_buffer_pool()
+    before = _stage_batches()
+    chunks = [(300, 120000, 4242, 150000),
+              (cas.MINIMUM_FILE_SIZE, cas.MINIMUM_FILE_SIZE + 1),
+              (9000, 8000, 7000)]
+    for c, sizes in enumerate(chunks):
+        files = []
+        for i, n in enumerate(sizes):
+            p = str(tmp_path / f"c{c}_{i}.bin")
+            files.append((p, _write(p, _pattern(n, seed=100 + 10 * c + i))))
+        ids, errors = staging.cas_ids_for_files(files, backend="jax")
+        assert not errors
+        for i, (p, n) in enumerate(files):
+            assert ids[i] == cas.cas_id_of_payload(
+                n, _oracle_payload(p, n)), (c, i)
+        assert _in_use(pool) == 0, c
     with pool._lock:
         assert pool._total == len(pool._free)
     assert len(pool._win) == 0
+    # two classes, two classes, one class
+    assert _stage_batches()["native"] - before["native"] == 5
 
 
 @requires_native
@@ -314,6 +312,23 @@ def test_stage_native_flag_modes(tmp_path, monkeypatch):
     for mode in ("off", "0", "no", "false"):
         monkeypatch.setenv("SDTPU_STAGE_NATIVE", mode)
         assert staging.stage_batch_native(files) is None, mode
+
+
+def test_stage_pool_lifecycle_and_gauge():
+    """The shared staging executor has an explicit lifecycle —
+    sd_stage_pool_workers reports its size, shutdown zeroes it and
+    drops the pool, the next use re-creates it."""
+    from spacedrive_tpu.telemetry import STAGE_POOL_WORKERS
+
+    pool = staging._pool()
+    assert STAGE_POOL_WORKERS.value > 0
+    staging.shutdown_stage_pool()
+    assert staging._STAGE_POOL is None
+    assert STAGE_POOL_WORKERS.value == 0
+    staging.shutdown_stage_pool()  # idempotent
+    again = staging._pool()        # lazily re-created for later users
+    assert again is not pool
+    assert STAGE_POOL_WORKERS.value > 0
 
 
 # -- the identifier's jax backend through the packed path ------------------

@@ -35,7 +35,7 @@ def clean_violations():
 def test_armed_by_conftest():
     assert threadctx.armed()
     names = {c.__name__ for c in threadctx.armed_classes()}
-    assert {"PipelineStats", "Counter", "Histogram", "Database",
+    assert {"StagePool", "Counter", "Histogram", "Database",
             "SyncManager", "HLC"} <= names, names
 
 
@@ -401,28 +401,3 @@ def test_shed_channel_concurrent_put_accounting():
         assert CHAN_HIGH_WATER.labels(
             name="bench.shed").value == peak
 
-
-def test_overlap_stats_guarded_increment_quiet():
-    """The real PipelineStats contract end-to-end: cross-thread
-    guarded increments record quietly; the declared samples list is
-    container-tracked."""
-    from spacedrive_tpu.ops.overlap import PipelineStats
-
-    stats = PipelineStats()
-    assert type(stats.samples).__name__ == "_TrackedList"
-
-    def stream():
-        for _ in range(100):
-            with stats._lock:
-                stats.h2d_bytes += 4096
-                stats.samples.append((0.1, 0.2, 0.3))
-
-    threads = [threading.Thread(target=stream) for _ in range(3)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert stats.h2d_bytes == 3 * 100 * 4096
-    assert len(stats.samples) == 300
-    assert not [v for v in sanitize.violations()
-                if v["kind"] == "data_race"]

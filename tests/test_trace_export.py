@@ -2,10 +2,10 @@
 and the Chrome-trace exporter (spacedrive_tpu/flight.py +
 tools/trace_export.py).
 
-Pins the PR's acceptance shapes on CPU:
-- a depth-3 sim-link identify run exports a schema-valid Chrome-trace
-  JSON with per-device stage/H2D/kernel/retire lanes and per-batch
-  bound attribution, race-recorder-clean (the autouse sanitizer
+Pins the acceptance shapes on CPU:
+- identify chunks on the host planes and the jax backend record
+  stage/H2D/kernel/retire lanes that export as a schema-valid
+  Chrome-trace JSON, race-recorder-clean (the autouse sanitizer
   fixture asserts the zero-violations half);
 - the exporter's golden schema: required keys, monotone ts, and a
   named process/thread for every pid/tid;
@@ -48,60 +48,22 @@ def _has_cryptography() -> bool:
 
 # -- recorder unit surface --------------------------------------------------
 
-def test_recorder_window_bound_attribution():
-    """A retired batch emits one `window` event naming the binding
-    component of max(stage, h2d, kernel) and the margin over the
-    runner-up."""
-    rec = flight.FlightRecorder()
-    run = flight.new_run_token()
-    t = 100.0
-    rec.record("stage", batch=7, t0=t, t1=t + 0.020, stream=1, run=run)
-    rec.record("h2d", batch=7, t0=t + 0.020, t1=t + 0.070, device="0",
-               run=run)
-    rec.record("kernel", batch=7, t0=t + 0.070, t1=t + 0.080,
-               device="0", run=run)
-    rec.record("retire", batch=7, t0=t + 0.080, t1=t + 0.085, run=run)
-    snap = rec.snapshot()
-    assert [e["lane"] for e in snap] == [
-        "stage", "h2d", "kernel", "retire", "window"]
-    win = snap[-1]
-    assert win["batch"] == 7
-    assert win["binding"] == "h2d"
-    # the window inherits the batch's DEVICE stream (h2d/kernel carry
-    # it; the shared retire pool does not) so attribution names which
-    # stream was bound
-    assert win["device"] == "0"
-    # margin = h2d (50 ms) - stage (20 ms), in µs with rounding slack
-    assert win["margin_us"] == pytest.approx(30_000, abs=200)
-    assert set(win["phases_us"]) == {"stage", "h2d", "kernel", "retire"}
-    # the whole-batch window spans first stage start → retire end
-    assert win["dur_us"] == pytest.approx(85_000, abs=200)
-
-
 def test_recorder_contract_quiet_under_threads():
-    """The threadctx half of the timeline ring: a post-arm recorder's
-    _open dict is container-tracked, and concurrent record() storms
-    from worker threads — every mutation under the declared _lock —
-    stay data_race-quiet (the autouse sanitizer fixture asserts zero
-    violations) while every batch still closes to exactly one window."""
+    """The threadctx half of the timeline ring: concurrent record()
+    storms from worker threads — every ring put under the declared
+    _lock — stay data_race-quiet (the autouse sanitizer fixture asserts
+    zero violations) and lose no event."""
     import threading
 
-    from spacedrive_tpu import threadctx
-
     rec = flight.FlightRecorder()
-    if threadctx.armed():
-        assert type(rec._open).__name__ == "_TrackedDict"
-    run = flight.new_run_token()
 
     def work(base):
         for i in range(50):
             b = base + i
-            rec.record("stage", batch=b, t0=1.0, t1=2.0, run=run)
-            rec.record("h2d", batch=b, t0=2.0, t1=3.0, device="0",
-                       run=run)
-            rec.record("kernel", batch=b, t0=3.0, t1=3.5, device="0",
-                       run=run)
-            rec.record("retire", batch=b, t0=3.5, t1=4.0, run=run)
+            rec.record("stage", batch=b, t0=1.0, t1=2.0)
+            rec.record("h2d", batch=b, t0=2.0, t1=3.0, device="0")
+            rec.record("kernel", batch=b, t0=3.0, t1=3.5, device="0")
+            rec.record("retire", batch=b, t0=3.5, t1=4.0)
 
     threads = [threading.Thread(target=work, args=(k * 1000,))
                for k in range(4)]
@@ -109,52 +71,10 @@ def test_recorder_contract_quiet_under_threads():
         t.start()
     for t in threads:
         t.join()
-    wins = [e for e in rec.snapshot() if e["lane"] == "window"]
-    assert len(wins) == 200
-    assert rec._open == {}  # every batch's window closed at retire
-
-
-def test_recorder_runless_scopes_never_accumulate_windows():
-    """Scopes that never retire (identify host-plane chunks pass no
-    run token) are pure lane events: thousands of them must leave the
-    open-window map EMPTY — the review-round leak regression (a
-    long-running node hashes chunks forever)."""
-    rec = flight.FlightRecorder()
-    for i in range(1000):
-        rec.record("stage", batch=i, t0=1.0, t1=2.0, scope="identify")
-        rec.record("kernel", batch=i, t0=2.0, t1=3.0,
-                   scope="identify")
-    assert rec._open == {}
-    # and even WITH run tokens, abandoned windows stop at the cap
-    for i in range(flight._OPEN_CAP * 2):
-        rec.record("stage", batch=0, t0=1.0, t1=2.0,
-                   run=flight.new_run_token())
-    assert len(rec._open) == flight._OPEN_CAP
-
-
-def test_recorder_runs_do_not_collide_on_batch_numbers():
-    """Two runs both dispatching a 'batch 3' keep separate windows:
-    each retire closes ITS run's phases (the review-round collision
-    regression — mixing runs corrupted one window and dropped the
-    other)."""
-    rec = flight.FlightRecorder()
-    r1, r2 = flight.new_run_token(), flight.new_run_token()
-    rec.record("stage", batch=3, t0=1.0, t1=1.1, run=r1)
-    rec.record("stage", batch=3, t0=2.0, t1=2.5, run=r2)
-    rec.record("h2d", batch=3, t0=1.1, t1=1.2, device="0", run=r1)
-    rec.record("h2d", batch=3, t0=2.5, t1=2.6, device="0", run=r2)
-    rec.record("kernel", batch=3, t0=1.2, t1=1.25, device="0", run=r1)
-    rec.record("kernel", batch=3, t0=2.6, t1=2.65, device="0", run=r2)
-    rec.record("retire", batch=3, t0=1.25, t1=1.3, run=r1)
-    rec.record("retire", batch=3, t0=2.65, t1=2.7, run=r2)
-    wins = [e for e in rec.snapshot() if e["lane"] == "window"]
-    assert len(wins) == 2
-    # run 2's stage (500 ms) binds; run 1's (100 ms) binds too — and
-    # neither window spans the other run's timestamps
-    assert all(w["binding"] == "stage" for w in wins)
-    assert wins[0]["dur_us"] == pytest.approx(300_000, abs=200)
-    assert wins[1]["dur_us"] == pytest.approx(700_000, abs=200)
-    assert rec._open == {}
+    snap = rec.snapshot()
+    assert len(snap) == 4 * 50 * 4
+    for lane in flight.LANES:
+        assert sum(e["lane"] == lane for e in snap) == 200, lane
 
 
 def test_recorder_ring_is_bounded_and_clearable():
@@ -177,18 +97,17 @@ def test_recorder_ring_is_bounded_and_clearable():
 
 def _synthetic_doc():
     rec = flight.FlightRecorder()
-    run = flight.new_run_token()
     t = 10.0
     for batch in (1, 2):
         b = t + batch * 0.1
         rec.record("stage", batch=batch, t0=b, t1=b + 0.03,
-                   stream=batch % 2, trace="feed", run=run)
+                   stream=batch % 2, trace="feed")
         rec.record("h2d", batch=batch, t0=b + 0.03, t1=b + 0.05,
-                   device=str(batch % 2), trace="feed", run=run)
+                   device=str(batch % 2), trace="feed")
         rec.record("kernel", batch=batch, t0=b + 0.05, t1=b + 0.06,
-                   device=str(batch % 2), trace="feed", run=run)
+                   device=str(batch % 2), trace="feed")
         rec.record("retire", batch=batch, t0=b + 0.06, t1=b + 0.07,
-                   trace="feed", run=run)
+                   trace="feed")
     spans = [
         {"span": "job/x", "ms": 50.0, "ts_us": 1_000_000,
          "trace": "aa", "id": "1", "ok": True},
@@ -218,11 +137,11 @@ def test_chrome_trace_golden_schema():
     named_tids = {(e["pid"], e["tid"]) for e in meta
                   if e["name"] == "thread_name"}
     assert {(e["pid"], e["tid"]) for e in xs} <= named_tids
-    # the per-device lanes and the bound-attribution lane exist
+    # the per-device lanes exist
     lane_names = {e["args"]["name"] for e in meta
                   if e["name"] == "thread_name"}
     assert {"dev0 h2d", "dev1 h2d", "dev0 kernel", "dev1 kernel",
-            "retire", "dev0 window", "dev1 window"} <= lane_names
+            "retire"} <= lane_names
     assert any(n.startswith("stage/w") for n in lane_names)
     # span events carry trace/id lineage in args
     span_evs = [e for e in xs if e["pid"] == flight.PID_SPANS]
@@ -262,69 +181,7 @@ def test_validator_rejects_seeded_violations():
     assert any("unknown ph" in p for p in probs)
 
 
-# -- the depth-3 acceptance shape -------------------------------------------
-
-def test_depth3_sim_link_run_exports_valid_trace(tmp_path, monkeypatch):
-    """A depth-3 sim-link identify run over two device streams exports
-    a schema-valid Chrome trace with per-device stage/H2D/kernel/
-    retire lanes and per-batch bound attribution — and the multi-
-    stream timeline writes are race-recorder-clean (the autouse
-    sanitizer fixture + the armed threadctx recorder assert that
-    half)."""
-    import jax
-
-    from spacedrive_tpu.ops import overlap
-    from tools.overlap_bench import _cheap_kernel
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        pytest.skip("needs the multi-device virtual mesh")
-    monkeypatch.setenv("SDTPU_SIM_LINK_GBPS", "0.02")
-    flight.RECORDER.clear()
-    corpus = overlap.make_sparse_corpus(str(tmp_path), 32 * 8,
-                                        120_000, 32)
-    _res, stats = overlap.run_overlapped(
-        corpus, kernel=_cheap_kernel, depth=3, devices=devs[:2],
-        calibrate_every=len(corpus))
-    snap = flight.RECORDER.snapshot()
-
-    # every measured batch got all four phases + a window
-    measured = len(corpus) - 1
-    by_lane = {}
-    for ev in snap:
-        by_lane.setdefault(ev["lane"], []).append(ev)
-    for lane in ("stage", "h2d", "kernel", "retire", "window"):
-        assert len(by_lane[lane]) == measured, (
-            lane, {k: len(v) for k, v in by_lane.items()})
-    # both device streams carried h2d/kernel work
-    assert {e["device"] for e in by_lane["h2d"]} == {"0", "1"}
-    # all events share the pipeline.run span's trace id
-    traces = {e.get("trace") for e in snap}
-    assert len(traces) == 1 and None not in traces
-    ring = tracing.recent_spans(limit=tracing.span_ring_capacity())
-    run_spans = [r for r in ring if r["span"] == "pipeline.run"]
-    assert run_spans and run_spans[-1]["trace"] in traces
-    # bound attribution: with the simulated link binding, h2d windows
-    # dominate; every window names a real component with real phases
-    # and the device stream it was bound on
-    for win in by_lane["window"]:
-        assert win["binding"] in ("stage", "h2d", "kernel")
-        assert win["phases_us"]["h2d"] > 0
-        assert win["device"] in ("0", "1")
-    assert any(w["binding"] == "h2d" for w in by_lane["window"])
-    assert {w["device"] for w in by_lane["window"]} == {"0", "1"}
-
-    # and the export is schema-valid with the per-device lanes visible
-    doc = flight.chrome_trace(node_name="depth3")
-    assert flight.validate_chrome_trace(doc) == []
-    lane_names = {e["args"]["name"] for e in doc["traceEvents"]
-                  if e["ph"] == "M" and e["name"] == "thread_name"}
-    assert {"dev0 h2d", "dev1 h2d", "dev0 kernel", "dev1 kernel",
-            "retire", "dev0 window", "dev1 window"} <= lane_names
-    out = tmp_path / "trace.json"
-    out.write_text(json.dumps(doc))
-    assert json.loads(out.read_text())["otherData"]["node"] == "depth3"
-
+# -- identify lanes ----------------------------------------------------------
 
 def test_identify_host_plane_records_timeline(tmp_path):
     """The host hashing planes get the same lanes (scope=identify):
@@ -345,24 +202,33 @@ def test_identify_host_plane_records_timeline(tmp_path):
     assert flight.validate_chrome_trace(doc) == []
 
 
-def test_identify_jax_plane_records_phase_lanes(tmp_path):
+@pytest.mark.parametrize("sizes,classes", [
+    ((700, 5000), 1),            # small only
+    ((120_000, 200_000), 1),     # large only (files > 100 KiB)
+    ((700, 120_000, 5000), 2),   # large and small
+], ids=["small", "large", "mixed"])
+def test_identify_jax_plane_records_phase_lanes(tmp_path, sizes, classes):
     """On the jax backend the identify lanes come from the phase
-    boundaries (stage+pack, dispatch, fetch): stage, h2d and retire,
-    back to back, and no host-clock kernel lane — the kernel is on the
-    device trace."""
+    boundaries (stage+pack, dispatch, fetch): one stage, h2d and retire
+    triple per size class, back to back under the chunk's one batch id,
+    and no host-clock kernel lane — the kernel is on the device
+    trace."""
+    from spacedrive_tpu.ops import cas
     from spacedrive_tpu.ops.staging import cas_ids_for_files
 
     files = []
-    for i, size in enumerate((700, 5000)):
+    for i, size in enumerate(sizes):
         p = tmp_path / f"f{i}.bin"
         p.write_bytes(bytes([i + 1]) * size)
         files.append((str(p), size))
     flight.RECORDER.clear()
     ids, errors = cas_ids_for_files(files, backend="jax")
-    assert not errors and len(ids) == 2
+    assert not errors and len(ids) == len(files)
+    assert all(ids[i] == cas.generate_cas_id(p, n)
+               for i, (p, n) in enumerate(files))
     snap = [e for e in flight.RECORDER.snapshot()
             if e.get("scope") == "identify"]
-    assert [e["lane"] for e in snap] == ["stage", "h2d", "retire"]
+    assert [e["lane"] for e in snap] == ["stage", "h2d", "retire"] * classes
     assert all(e["device"] == "jax" for e in snap)
     assert len({e["batch"] for e in snap}) == 1
     for a, b in zip(snap, snap[1:]):
@@ -516,21 +382,3 @@ def test_trace_export_cli_self_check(tmp_path):
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
     assert out.returncode == 1
     assert "thread_name" in out.stderr
-
-
-def test_overlap_bench_trace_flag(tmp_path, monkeypatch):
-    """`overlap_bench --trace` ships a schema-valid trace artifact
-    next to the sweep JSON (exercised in-process via run_sweep + the
-    same export path the flag drives)."""
-    from tools import overlap_bench
-
-    flight.RECORDER.clear()
-    rows = overlap_bench.run_sweep(
-        depths=[3], links=[0.125], batch=64, batches=4,
-        cheap_kernel=True, calibrate_every=4)
-    assert rows and rows[0]["measured_files_per_sec"] > 0
-    doc = flight.chrome_trace(node_name="overlap_bench")
-    assert flight.validate_chrome_trace(doc) == []
-    lanes = {e["args"]["name"] for e in doc["traceEvents"]
-             if e["ph"] == "M" and e["name"] == "thread_name"}
-    assert any(n.endswith("window") for n in lanes), lanes

@@ -175,7 +175,7 @@ def test_span_family_registry_shape():
     family the engine uses is present."""
     from spacedrive_tpu import tracing
 
-    assert {"cas_ids", "job", "job.step", "p2p", "pipeline.run", "rpc",
+    assert {"cas_ids", "job", "job.step", "p2p", "rpc",
             "sync.pull", "sync.serve"} <= set(tracing.SPAN_FAMILIES)
     with pytest.raises(ValueError):
         tracing.declare_span("Bad/Family")

@@ -3,10 +3,9 @@ round.
 
 The repo records one `BENCH_rNN.json` per growth round, but the
 artifact shape evolved with the harnesses: driver-wrapped rounds
-(`{n, cmd, rc, tail, parsed}`) carry bench.py's kernel line or an
-overlap_bench sweep (r06), r07 wraps a fleet-observatory snapshot,
-r08/r09 are raw load_bench artifacts, r10 is a raw overlap_bench
-artifact (r01–r05, remote-chip kernel rounds, were removed in PR 21).
+(`{n, cmd, rc, tail, parsed}`, r07 wraps a fleet-observatory snapshot)
+and raw load_bench artifacts (r08/r09). The kernel and identify-ring
+rounds were removed with the harnesses that wrote them.
 Reading the trajectory therefore meant opening many files with several
 schemas. This tool normalizes every round into one row — headline
 metric, unit, and the round's own gate/validity verdict — validates
@@ -70,33 +69,7 @@ def normalize(n: int, doc: Dict[str, Any]) -> Dict[str, Any]:
         doc = doc["parsed"]
 
     metric = doc.get("metric") or doc.get("bench")
-    if metric == "cas_ids_per_sec_large_files":
-        row["bench"] = "kernel CAS-ID"
-        row["unit"] = doc.get("unit") or "files/s"
-        row["value"] = doc.get("value")
-        if not isinstance(row["value"], (int, float)) or row["value"] <= 0:
-            probs.append(f"r{n:02d}: kernel value missing")
-        vs = doc.get("vs_baseline")
-        if isinstance(vs, (int, float)):
-            row["note"] = f"{vs:g}x native baseline"
-    elif metric == "overlap_bench":
-        row["bench"] = "overlap pipeline"
-        row["unit"] = doc.get("unit") or "files/s"
-        sweep = doc.get("sweep")
-        if not isinstance(sweep, list) or not sweep:
-            probs.append(f"r{n:02d}: overlap sweep missing")
-        else:
-            best = max(sweep,
-                       key=lambda s: s.get("measured_files_per_sec") or 0)
-            row["value"] = best.get("measured_files_per_sec")
-            ratio = best.get("ratio")
-            row["note"] = (f"depth {best.get('depth')}, "
-                           f"{ratio:.0%} of component bound"
-                           if isinstance(ratio, (int, float)) else
-                           f"depth {best.get('depth')}")
-            if not isinstance(row["value"], (int, float)):
-                probs.append(f"r{n:02d}: overlap measured rate missing")
-    elif metric == "fleet_observatory":
+    if metric == "fleet_observatory":
         row["bench"] = "fleet observatory"
         nodes = doc.get("nodes")
         row["unit"] = "nodes"

@@ -69,17 +69,16 @@ def seed_trace(trace_id: str) -> None:
         with tracing.span("sync.pull", library="fleet-self-check"):
             with tracing.span("job.step", step=1):
                 pass
-    run = flight.new_run_token()
     t0 = time.perf_counter()
     rec = flight.RECORDER
     rec.record("stage", batch=1, t0=t0, t1=t0 + 0.004,
-               trace=trace_id, run=run)
+               trace=trace_id)
     rec.record("h2d", batch=1, t0=t0 + 0.004, t1=t0 + 0.007,
-               device="0", trace=trace_id, run=run)
+               device="0", trace=trace_id)
     rec.record("kernel", batch=1, t0=t0 + 0.007, t1=t0 + 0.008,
-               device="0", trace=trace_id, run=run)
+               device="0", trace=trace_id)
     rec.record("retire", batch=1, t0=t0 + 0.008, t1=t0 + 0.009,
-               trace=trace_id, run=run)
+               trace=trace_id)
 
 
 async def serve(name: str, trace_id: str) -> None:

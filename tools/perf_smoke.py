@@ -2,8 +2,7 @@
 
 Generates a deterministic corpus (tools/make_corpus.py), runs the real
 pipeline through the real job system — index → identify → validate →
-exact-dup — and prints one JSON line per stage with files/sec. This is
-the workload-level complement to bench.py's kernel-level number
+exact-dup — and prints one JSON line per stage with files/sec
 (BASELINE.json configs 1–3; config 4 runs when images are requested,
 config 5 is this with --files 1000000 across multiple locations).
 
@@ -242,19 +241,13 @@ async def run(files: int, backend: str, images: int, keep: str | None,
             }, "over_budget": sorted(
                 name for name, n in traces.items()
                 if n > jit_registry.CONTRACTS[name].max_traces)})
-            # Pipeline-shape proof next to the jit stage: the depth-N ring's
-            # registry families (depth high-water, stall seconds, H2D
-            # bytes/seconds, donated-buffer reuse, per-device batch split)
-            # plus the configured depth — so a bench artifact shows HOW the
+            # Staging-shape proof next to the jit stage: the stage pool's
+            # registry families — so a bench artifact shows HOW the
             # identify stream was fed, not just how fast it went.
-            from spacedrive_tpu.ops import overlap as overlap_mod
-
             snap = telemetry.snapshot()
             emit({"stage": "pipeline",
-                  "depth_configured": overlap_mod.pipeline_depth(),
                   "metrics": {name: value for name, value in snap.items()
-                              if name.startswith(("sd_pipeline_",
-                                                  "sd_stage_pool_"))}})
+                              if name.startswith("sd_stage_pool_")}})
             # Saturation evidence next to the numbers: subsystem states +
             # top attribution over the WHOLE run's window (the monitor's
             # cursors were established before the corpus stage), schema-

@@ -224,8 +224,8 @@ def input_problems(doc: object) -> list:
     """Validate a stored artifact: a full bundle file, a bare header,
     a list of either, a `{"incidents": [...]}` artifact body, or a
     BENCH artifact whose `incidents` section is the bench shape
-    `{"enabled", "headers", "deduped"}` (load_bench / overlap_bench
-    --json output validates directly)."""
+    `{"enabled", "headers", "deduped"}` (load_bench --json output
+    validates directly)."""
     from spacedrive_tpu.incidents import (
         validate_incident_bundle,
         validate_incident_header,

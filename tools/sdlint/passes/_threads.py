@@ -12,8 +12,8 @@ contexts it is statically reachable from:
   `threadctx.call_threadsafe`;
 - ``worker:<qual>`` — one context per thread-submission ROOT: a
   function handed to `asyncio.to_thread`, `run_in_executor`,
-  `executor.submit` (the ops/staging.py pool, the per-device dispatch
-  streams in ops/overlap.py), or `threading.Thread(target=...)`,
+  `executor.submit` (the ops/staging.py pool), or
+  `threading.Thread(target=...)`,
   plus everything it calls synchronously. Each root is its OWN
   context: two different submissions may run on different pool
   threads concurrently;
@@ -370,8 +370,8 @@ def _lock_of(expr: ast.AST) -> Optional[str]:
 
 def _annotation_classes(fn: FuncInfo, known: Set[str]) -> Dict[str, str]:
     """param name → class name, for parameters annotated with a known
-    (registered or project) class — `stats: Optional[PipelineStats]`
-    resolves `stats.h2d_bytes += ...` to PipelineStats."""
+    (registered or project) class — `stats: Optional[Stats]` resolves
+    `stats.h2d_bytes += ...` to Stats."""
     out: Dict[str, str] = {}
     args_node = getattr(fn.node, "args", None)
     if args_node is None:

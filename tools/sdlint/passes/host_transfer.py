@@ -3,7 +3,7 @@
 A `np.asarray(...)`, `.item()`, implicit `bool(arr)`, or
 `block_until_ready()` on a live device value forces a synchronous
 device→host round trip, and in the identify loop a single stray
-fetch serializes the whole depth-N overlap pipeline.
+fetch serializes the chunk's dispatch behind its own fetch.
 The discipline: every transfer of jit results happens at a DECLARED
 point — a `with jit_registry.io("<contract>"):` scope whose contract
 (ops/jit_registry.py) is declared `host_transfer=True` — or runs

@@ -13,8 +13,8 @@ enforces the binding and the compile-stability idioms around it:
 - `unknown-jit-name`  — `tracked("x")` where no contract `x` exists;
 - `call-time-jit`     — `jax.jit(fn)` constructed inside a function
   body whose contract is not a declared FACTORY: a fresh jit wrapper
-  per call throws away the trace cache (the round-1..9 overlap.py:166
-  shape — every calibration pause recompiled the kernel);
+  per call throws away the trace cache (the shape an early identify
+  pipeline shipped — every calibration pause recompiled the kernel);
 - `jit-in-loop`       — `jax.jit(...)` lexically inside a for/while:
   strictly worse than call-time construction;
 - `static-args-mismatch` / `static-argnums` — the site's

@@ -1,9 +1,10 @@
 """Pass: shared-mutation — every mutable attribute of a multi-context
 class obeys its declared ownership contract.
 
-PR 8's review rounds were spent hand-fixing exactly this bug class:
-`PipelineStats` plain `+=` from two device streams lost updates, and
-the stage-pool gauge clobbered across a concurrent pool swap. The
+Two review rounds were once spent hand-fixing exactly this bug class:
+a pipeline stats object's plain `+=` from two device streams lost
+updates, and the stage-pool gauge clobbered across a concurrent pool
+swap. The
 contract table lives in `spacedrive_tpu/threadctx.py` (one
 `declare_owner(...)` per class, one kind per mutable attribute); this
 pass derives thread contexts from the call graph (`_threads.py`:
@@ -15,7 +16,7 @@ Codes:
 
 - ``unguarded-write``     — a post-init write to a `guarded_by(L)`
   attribute outside a lexical `with <L>:` block (the encoded
-  `PipelineStats.h2d_bytes` `+=` shape).
+  `stats.h2d_bytes` `+=` shape).
 - ``wrong-context-write`` — a `loop_only` attribute written from a
   function reachable from a worker/atexit context.
 - ``multi-thread-write``  — a `single_thread` attribute whose mutation
@@ -137,7 +138,7 @@ class SharedMutationPass:
                                if s.aug else "write")
                             + f" holds {sorted(s.locks) or 'no lock'}"
                             " — a concurrent writer loses updates "
-                            "(the PR 8 PipelineStats shape)",
+                            "(the lost-update stats shape)",
                             s.lineno))
             elif kind == "loop_only":
                 for s in group:

@@ -67,18 +67,17 @@ def build_self_check_trace() -> dict:
             pass
 
     rec = flight.FlightRecorder()
-    run = flight.new_run_token()
     t0 = time.perf_counter()
     for batch, dev in ((1, "0"), (2, "1")):
         b = t0 + batch * 0.010
         rec.record("stage", batch=batch, t0=b, t1=b + 0.004,
-                   stream=batch % 2, trace="selfcheck", run=run)
+                   stream=batch % 2, trace="selfcheck")
         rec.record("h2d", batch=batch, t0=b + 0.004, t1=b + 0.007,
-                   device=dev, trace="selfcheck", run=run)
+                   device=dev, trace="selfcheck")
         rec.record("kernel", batch=batch, t0=b + 0.007, t1=b + 0.008,
-                   device=dev, trace="selfcheck", run=run)
+                   device=dev, trace="selfcheck")
         rec.record("retire", batch=batch, t0=b + 0.008, t1=b + 0.009,
-                   trace="selfcheck", run=run)
+                   trace="selfcheck")
     spans = [r for r in tracing.recent_spans(
         limit=tracing.span_ring_capacity()) if "ts_us" in r]
     return flight.chrome_trace(spans=spans, timeline=rec.snapshot(),
@@ -114,16 +113,14 @@ def build_fleet_self_check_trace() -> dict:
             remote_spans.append(r)
 
     rec = flight.FlightRecorder()
-    run = flight.new_run_token()
     t0 = time.perf_counter()
-    rec.record("stage", batch=1, t0=t0, t1=t0 + 0.004, trace=tid,
-               run=run)
+    rec.record("stage", batch=1, t0=t0, t1=t0 + 0.004, trace=tid)
     rec.record("h2d", batch=1, t0=t0 + 0.004, t1=t0 + 0.007,
-               device="0", trace=tid, run=run)
+               device="0", trace=tid)
     rec.record("kernel", batch=1, t0=t0 + 0.007, t1=t0 + 0.008,
-               device="0", trace=tid, run=run)
+               device="0", trace=tid)
     rec.record("retire", batch=1, t0=t0 + 0.008, t1=t0 + 0.009,
-               trace=tid, run=run)
+               trace=tid)
 
     return flight.fleet_chrome_trace(
         [{"node": "local", "spans": local_spans,

@@ -15,8 +15,8 @@ Usage: python tools/validator_device_bench.py [n_files] [file_kb]
 
 --kernel prints the KERNEL-SIDE figure instead (VERDICT r5 weak #5):
 the checksum hasher behind checksums_words_batched timed as ITERS
-chained executions inside one jit with a loop-carried dependency —
-bench.py's CAS methodology, so the number excludes the dispatch +
+chained executions inside one jit with a loop-carried dependency, so
+the number excludes the dispatch +
 D2H sync of any per-call wall timing. files/s + GB/s on whatever
 device jax resolves (labeled as such).
 """
@@ -114,7 +114,7 @@ async def run(n_files: int, file_kb: int) -> None:
 def kernel_figure(n_files: int, file_kb: int, iters: int = 30) -> None:
     """Chained-in-jit throughput of the batched-validator checksum
     kernel (ops/blake3_jax hasher over a checksums_words_batched-shaped
-    grid). Mirrors bench.py: ITERS executions chained through lax.scan
+    grid): ITERS executions chained through lax.scan
     with a loop-carried dependency so per-iteration wall is
     t_fixed/ITERS + t_marginal, best-of-3."""
     import jax
@@ -182,8 +182,7 @@ def kernel_figure(n_files: int, file_kb: int, iters: int = 30) -> None:
         "iters": iters,
         "chunk_grid_C": C,
         "device": f"{dev.platform}:{getattr(dev, 'device_kind', '?')}",
-        "methodology": "ITERS chained in one jit (bench.py CAS "
-                       "methodology), best-of-3",
+        "methodology": "ITERS chained in one jit, best-of-3",
     }))
 
 
